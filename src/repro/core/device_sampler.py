@@ -287,8 +287,6 @@ class DeviceRecencySampler:
         """
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.sharding import SHARD_MAP_KW, shard_map
-
         mesh, axis = self._mesh, self._mesh_axis
         per, k, directed = self._per, self.k, self.directed
         state_specs = {"buf": P(axis), "cc": P(axis)}
@@ -311,12 +309,12 @@ class DeviceRecencySampler:
             cc = jnp.where(owned[:, None], cc, 0)
             return (jax.lax.psum(rows, axis), jax.lax.psum(cc, axis))
 
-        upd = shard_map(update_body, mesh=mesh,
-                        in_specs=(state_specs, rep, rep, rep, rep, rep),
-                        out_specs=state_specs, **SHARD_MAP_KW)
-        smp = shard_map(sample_body, mesh=mesh,
-                        in_specs=(state_specs, rep), out_specs=(rep, rep),
-                        **SHARD_MAP_KW)
+        upd = jax.shard_map(update_body, mesh=mesh,
+                            in_specs=(state_specs, rep, rep, rep, rep, rep),
+                            out_specs=state_specs, check_vma=False)
+        smp = jax.shard_map(sample_body, mesh=mesh,
+                            in_specs=(state_specs, rep),
+                            out_specs=(rep, rep), check_vma=False)
         self._sharded_update_donated = jax.jit(upd, donate_argnums=(0,))
         self._sharded_update_copying = jax.jit(upd)
         self._sharded_sample = jax.jit(

@@ -380,8 +380,6 @@ class DeviceUniformSampler:
         module docstring for the two-psum combine)."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.sharding import SHARD_MAP_KW, shard_map
-
         mesh, axis = self._mesh, self._mesh_axis
         k, L = self.k, self._L
         adj_specs = {"adj_nbr": P(axis), "adj_t": P(axis), "adj_e": P(axis),
@@ -414,9 +412,9 @@ class DeviceUniformSampler:
                 jnp.where(owned[:, None, None], rows, 0), axis)
             return rows, n_valid
 
-        smp = shard_map(sample_body, mesh=mesh,
-                        in_specs=(adj_specs, rep, rep, rep),
-                        out_specs=(rep, rep), **SHARD_MAP_KW)
+        smp = jax.shard_map(sample_body, mesh=mesh,
+                            in_specs=(adj_specs, rep, rep, rep),
+                            out_specs=(rep, rep), check_vma=False)
 
         def sample(adj, seeds, query_t, rng_key):
             rows, n_valid = smp(adj, seeds, query_t, rng_key)

@@ -471,6 +471,8 @@ class PrefetchLoader:
                     # Starvation: the producer is the bottleneck here.
                     tel.count("loader/consumer_stall")
                     continue
+                if stop.is_set():  # closed: queued batches are dropped
+                    return
                 if tel.enabled:
                     tel.observe("loader/prefetch_wait",
                                 time.perf_counter() - wait_t0)
@@ -484,7 +486,11 @@ class PrefetchLoader:
                 tel.count("loader/batches")
                 yield item
         finally:
+            # Join the producer too: left running, it may still be inside a
+            # jitted hook call when the interpreter shuts down.
             stop.set()
+            if thread.is_alive():
+                thread.join(timeout=5)
             with self._active_lock:
                 self._active = [a for a in self._active if a[0] is not stop]
 

@@ -32,11 +32,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import compression as comp
 from repro.optim import AdamWConfig, adamw_init, adamw_update
-
-# The version-compat shard_map shim is shared with the sharded samplers
-# and lives with the other mesh helpers in distributed/sharding.py.
-from repro.distributed.sharding import SHARD_MAP_KW as _SHARD_MAP_KW
-from repro.distributed.sharding import shard_map as _shard_map
 from repro.distributed.sharding import sync_state_masked_psum
 
 
@@ -108,12 +103,12 @@ class DataParallelTrainer:
         pspec = P()  # replicated params/opt/err/state
         bspec = jax.tree.map(lambda _: P(None, self.axis), {"x": 0})["x"]
 
-        smapped = _shard_map(
+        smapped = jax.shard_map(
             shard_step,
             mesh=self.mesh,
             in_specs=(pspec, pspec, pspec, pspec, P(None, self.axis)),
             out_specs=(pspec, pspec, pspec, pspec, P()),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )
         self._step = jax.jit(smapped)
         return self._step

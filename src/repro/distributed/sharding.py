@@ -12,8 +12,6 @@ no mesh is active (CPU tests).
 This module is also the home of the *node-partitioned sampler state* layout
 shared by the device-resident temporal samplers (see ``docs/sharding.md``):
 
-  * ``shard_map`` — the version-compat resolved ``jax.shard_map`` (used by
-    both the DP trainer and the sharded samplers);
   * ``make_node_mesh`` — a 1-D mesh over the first N devices, axis "data";
   * ``node_rows_per_shard`` / ``row_sharding`` / ``replicated_sharding`` —
     the row-wise node-id partition arithmetic and the ``NamedSharding``s
@@ -31,18 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# shard_map moved to the jax namespace (and check_rep became check_vma)
-# across JAX releases; resolve whichever the installed version exposes once,
-# here, for every shard_map consumer in the repo (DP trainer, sharded
-# samplers).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-    SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover - older JAX
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-    SHARD_MAP_KW = {"check_rep": False}
 
 AxisVal = Union[None, str, Tuple[str, ...]]
 Rules = Dict[str, AxisVal]

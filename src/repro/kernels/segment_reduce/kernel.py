@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _segment_sum_kernel(seg_ref, data_ref, o_ref, *, num_segments: int,
                         block_e: int):
@@ -36,12 +34,16 @@ def _segment_sum_kernel(seg_ref, data_ref, o_ref, *, num_segments: int,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    seg = seg_ref[...]  # (block_e,) int32; -1 = padding
+    seg = seg_ref[...]  # (1, block_e) int32; -1 = padding
     data = data_ref[...].astype(jnp.float32)  # (block_e, D)
     # one-hot (G, block_e) on the fly; padding rows match no segment
     seg_grid = jax.lax.broadcasted_iota(jnp.int32, (num_segments, block_e), 0)
-    onehot = (seg_grid == seg[None, :]).astype(jnp.float32)
-    o_ref[...] += jax.lax.dot(onehot, data).astype(o_ref.dtype)
+    onehot = (seg_grid == seg).astype(jnp.float32)
+    # HIGHEST keeps the f32 data exact through the MXU (the one-hot side
+    # is exact at any precision).
+    o_ref[...] += jax.lax.dot(
+        onehot, data, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def segment_sum_kernel(data, seg_ids, num_segments: int, *,
@@ -49,7 +51,10 @@ def segment_sum_kernel(data, seg_ids, num_segments: int, *,
     """data: (E, D); seg_ids: (E,) int32 in [0, num_segments) or -1 padding.
 
     Returns (num_segments, D). ``num_segments * D`` must fit VMEM; the ops
-    wrapper tiles bigger segment spaces.
+    wrapper tiles bigger segment spaces. The ids travel as one (1, E) row,
+    blocked (1, block_e), so their tiling matches XLA's for any
+    ``block_e`` that is a multiple of 128 (a 1-D id block is refused on
+    TPU: XLA tiles s32[E] by 1024, Mosaic the block by ``block_e``).
     """
     E, D = data.shape
     pad = (-E) % block_e
@@ -63,14 +68,14 @@ def segment_sum_kernel(data, seg_ids, num_segments: int, *,
                           block_e=block_e),
         grid=(ne,),
         in_specs=[
-            pl.BlockSpec((block_e,), lambda i: (i,)),
+            pl.BlockSpec((1, block_e), lambda i: (0, i)),
             pl.BlockSpec((block_e, D), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((num_segments, D), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_segments, D), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(seg_ids.astype(jnp.int32), data)
+    )(seg_ids.astype(jnp.int32).reshape(1, -1), data)
     return out

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssd_chunk_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_scr, *,
                       chunk: int):
@@ -104,7 +102,7 @@ def ssd_chunk_kernel(x, dt, a, B, C, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, chunk, P), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((H, Sp, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -5,21 +5,29 @@ epoch time. On TPU the hot loop is: for each seed node, attend its K most
 recent neighbors (K = 10..32, padded). See ``docs/kernels.md`` for the full
 memory-space layout and parity-testing story.
 
+Layout. Every kernel works on 2-D, head-flattened rows: a seed's query is
+one ``(1, H*D)`` row and its neighborhood one ``(K, H*D)`` slab, with K on
+sublanes and the heads side by side on lanes. The wrappers reshape the
+public ``(S, H, D)`` / ``(N, H, D)`` arrays to ``(S, H*D)`` / ``(N, H*D)``;
+the fused kernels also pad H*D to a multiple of 128 lanes (zeros no head
+reads), because a single-row DMA must move whole lane tiles. Attention
+runs on the VPU:
+per head, the scores are a lane reduction of ``k * (q * head_mask)`` and
+the output a sublane reduction of ``p * v`` — exact f32 and free of the
+batched contractions without a free lhs dimension (``"hd,khd->hk"``) that
+Mosaic cannot lower. The only MXU work is the plain 2-D bias matmuls,
+pinned to ``Precision.HIGHEST``.
+
 ``temporal_attention_kernel`` is the un-fused baseline: it consumes
 pre-gathered ``(S, K, H, D)`` k/v tensors, tiles seeds into VMEM blocks and
-keeps the whole (block_s, K) score tile resident — one softmax pass, no HBM
-round-trip for the intermediate scores.
+walks the block's seeds with the same per-seed attention as the fused path.
 
 Grid: (num_seed_blocks,) — embarrassingly parallel over seeds.
 Blocks (VMEM):
-  q:    (block_s, H, D)
-  k/v:  (block_s, K, H, D)   — gathered neighbor features (K padded to a
-                               lane multiple by ops.py)
-  mask: (block_s, K)
-  o:    (block_s, H, D)
-
-With block_s=128, K=32, H=2, D=64 the working set is ~4.5 MiB f32 — well
-inside the 16 MiB VMEM budget, and head_dim 64/128 keeps MXU tiles aligned.
+  q:    (block_s, H*D)
+  k/v:  (block_s, K, H*D)   — gathered neighbor features
+  mask: (block_s, K, 1)
+  o:    (block_s, H*D)
 
 ``fused_temporal_layer_kernel`` is the device-sampling variant (the layer-1
 compute of TGAT/TGN when ``device_sampling=True``): instead of consuming
@@ -34,19 +42,20 @@ additive biases computed in VMEM:
           + phi(t_s - t_j) @ Wt_k               # in-kernel time bias
           + edge_feats[eid_j] @ We_k            # DMA'd edge bias
 
-so the fat ``(S, K, H, D)`` intermediates never exist in HBM. The buffer
-row (ids, times, eids) and each neighbor's table/edge-feature row are DMA'd
-from HBM into VMEM scratch per seed; seed ids and query times arrive via
-scalar prefetch (``PrefetchScalarGridSpec``) so DMA source indices are known
-before the kernel body runs. Seeds may be negative (hop-2 frontier padding):
-the DMA index is clamped and the whole row masked out, so the 2-hop TGAT
-frontier can run through the kernel unclamped.
+so the fat ``(S, K, H, D)`` intermediates never exist in HBM. XLA gathers
+each seed's packed buffer row (ids, times, eids: an ``(S, K, 3)`` int32
+index block) up front; its clamped ids reach SMEM as DMA source indices
+and its validity / time-delta columns reach VMEM, blocked with the seeds.
+Each neighbor's table/edge-feature row is then DMA'd from HBM into VMEM
+scratch. Seeds may be negative (hop-2 frontier padding): the DMA index is
+clamped and the whole row masked out, so the 2-hop TGAT frontier can run
+through the kernel unclamped.
 
 Per-seed DMAs are double-buffered: while seed ``j``'s neighborhood is being
-reduced on the VPU/MXU, seed ``j+1``'s buffer row and its K neighbor-row
-copies (issued back-to-back, all in flight at once) land in the other half
-of a 2-slot scratch. ``fused_recency_attention_kernel`` (the PR-1 surface:
-ids-only buffer, no bias folding) is kept as a thin wrapper and runs through
+reduced on the VPU/MXU, seed ``j+1``'s K neighbor-row copies (issued
+back-to-back, all in flight at once) land in the other half of a 2-slot
+scratch. ``fused_recency_attention_kernel`` (ids-only buffer, no bias
+folding) is kept as a thin wrapper and runs through
 the same double-buffered body.
 
 ``fused_temporal_layer_bwd_kernel`` is the flash-attention-style backward:
@@ -74,27 +83,63 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b, contract=((1,), (0,))):
+    """f32 2-D matmul on the MXU at full precision (``contract`` as in
+    ``lax.dot_general``: NN by default, ``((0,), (0,))`` for aᵀb,
+    ``((1,), (1,))`` for abᵀ)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _head_masks(heads: int, hdim: int, width: int):
+    """One (1, width) f32 lane mask per head, selecting that head's D lanes
+    (lanes past H*D are padding and belong to no head)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return [((lane >= h * hdim) & (lane < (h + 1) * hdim)).astype(jnp.float32)
+            for h in range(heads)]
+
+
+def _masked_softmax(s, valid):
+    """Softmax over the K sublanes of an (K, 1) score column, with invalid
+    slots excluded and a fully-invalid column zeroed — identical to the
+    oracle's ``softmax`` + ``where(mask.any(), ·, 0)``."""
+    s = jnp.where(valid > 0, s, NEG_INF)
+    p = jnp.exp(s - s.max(axis=0, keepdims=True))
+    p = p / jnp.maximum(p.sum(axis=0, keepdims=True), 1e-30)
+    return p * valid.max(axis=0, keepdims=True)
+
+
+def _attend(q, k, v, valid, masks):
+    """One seed's multi-head attention on the VPU.
+
+    q: (1, H*D) scaled query; k, v: (K, H*D); valid: (K, 1) f32 in {0, 1};
+    masks: ``_head_masks``. Returns the (1, H*D) head-concatenated output.
+    """
+    out = jnp.zeros(q.shape, jnp.float32)
+    for m in masks:
+        p = _masked_softmax(jnp.sum(k * (q * m), axis=1, keepdims=True),
+                            valid)
+        out = out + jnp.sum(p * v, axis=0, keepdims=True) * m
+    return out
 
 
 def _temporal_attention_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *,
-                               scale: float):
-    q = q_ref[...].astype(jnp.float32) * scale  # (bs, H, D)
-    k = k_ref[...].astype(jnp.float32)  # (bs, K, H, D)
-    v = v_ref[...].astype(jnp.float32)
-    mask = mask_ref[...]  # (bs, K)
+                               scale: float, block_s: int, heads: int,
+                               hdim: int):
+    masks = _head_masks(heads, hdim, heads * hdim)
 
-    s = jnp.einsum("shd,skhd->shk", q, k)  # (bs, H, K)
-    s = jnp.where(mask[:, None, :], s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    denom = p.sum(axis=-1, keepdims=True)
-    p = p / jnp.maximum(denom, 1e-30)
-    any_valid = mask.any(axis=-1)[:, None, None]
-    p = jnp.where(any_valid, p, 0.0)
-    o_ref[...] = jnp.einsum("shk,skhd->shd", p, v).astype(o_ref.dtype)
+    def per_seed(j, carry):
+        q = q_ref[pl.ds(j, 1), :].astype(jnp.float32) * scale   # (1, H*D)
+        o = _attend(q, k_ref[j].astype(jnp.float32),
+                    v_ref[j].astype(jnp.float32), mask_ref[j], masks)
+        o_ref[pl.ds(j, 1), :] = o.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, block_s, per_seed, 0)
 
 
 def temporal_attention_kernel(q, k, v, mask, *, block_s: int = 128,
@@ -107,203 +152,251 @@ def temporal_attention_kernel(q, k, v, mask, *, block_s: int = 128,
 
     block_s = min(block_s, S)
     pad = (-S) % block_s
-    if pad:
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-        k = jnp.pad(k, ((0, pad), (0, 0), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, pad), (0, 0), (0, 0), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))
+    q2 = jnp.pad(q.reshape(S, H * D), ((0, pad), (0, 0)))
+    k2 = jnp.pad(k.reshape(S, K, H * D), ((0, pad), (0, 0), (0, 0)))
+    v2 = jnp.pad(v.reshape(S, K, H * D), ((0, pad), (0, 0), (0, 0)))
+    m2 = jnp.pad(mask.astype(jnp.float32)[..., None],
+                 ((0, pad), (0, 0), (0, 0)))
     ns = (S + pad) // block_s
 
     out = pl.pallas_call(
-        functools.partial(_temporal_attention_kernel, scale=scale),
+        functools.partial(_temporal_attention_kernel, scale=scale,
+                          block_s=block_s, heads=H, hdim=D),
         grid=(ns,),
         in_specs=[
-            pl.BlockSpec((block_s, H, D), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_s, K, H, D), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((block_s, K, H, D), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((block_s, K), lambda i: (i, 0)),
+            pl.BlockSpec((block_s, H * D), lambda i: (i, 0)),
+            pl.BlockSpec((block_s, K, H * D), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block_s, K, H * D), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block_s, K, 1), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_s, H, D), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((S + pad, H, D), q.dtype),
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((block_s, H * D), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((S + pad, H * D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=interpret,
-    )(q, k, v, mask)
-    return out[:S]
+    )(q2, k2, v2, m2)
+    return out[:S].reshape(S, H, D)
 
 
-def _make_stager(seeds_ref, buf_hbm, k_hbm, v_hbm, ef_hbm,
-                 row_smem, row_vmem, k_scr, v_scr, e_scr,
-                 sem_row, sem_rowv, sem_k, sem_v, sem_e,
-                 *, block_s: int, kbuf: int, has_edge: bool):
+_LANE = 128
+
+
+def _round_up(n: int, m: int = _LANE) -> int:
+    return -(-n // m) * m
+
+
+def _pad2(x, rows: int, cols: int):
+    """Zero-pad a 2-D array up to (rows, cols)."""
+    return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
+
+
+def _row_table(x, width: int):
+    """(R, d) -> (R, 1, width), zero-padded on lanes: one whole (1, width)
+    tile per index, which a single-row DMA out of HBM may copy on TPU (a
+    row of an (R, d) array tiled (8, 128) slices a tile and is refused)."""
+    return _pad2(x, x.shape[0], width).reshape(x.shape[0], 1, width)
+
+
+def _layer_inputs(q, k_table, v_table, seeds, seed_times, buf, time_w,
+                  time_b, wt_k, wt_v, edge_feats, we_k, we_v, block_s):
+    """Assemble the fused forward/backward pallas_call inputs.
+
+    XLA gathers each seed's packed buffer row up front — an (S, K, 3)
+    int32 index block, not the fat (S, K, H, D) features — and splits it
+    into the DMA indices (``idx``: (S, 2K) clamped neighbor and edge ids,
+    blocked into SMEM) and the vector columns (``cols``: (S, 3, K) f32 slot
+    validity, query/neighbor time delta and edge validity, blocked into
+    VMEM). The node tables and the edge-feature storage become
+    ``_row_table``s in ANY/HBM, lane-padded to multiples of 128; q and
+    the weight groups are lane-padded to the same H*D width with zeros,
+    which the attention never reads (the head masks cover the real lanes).
+
+    Returns ``(lead, rest, meta)``: the blocked seed-axis operands
+    ``[idx, cols, q]`` and the grid-invariant ``[k, v, time group, edge
+    group]``, each a list of ``(operand, BlockSpec)``, and the static
+    sizes the wrappers need.
+    """
+    S, H, D = q.shape
+    HD = H * D
+    HDp = _round_up(HD)
+    K = buf.shape[1]
+    block_s = min(block_s, S)
+    pad = (-S) % block_s
+
+    seeds = seeds.astype(jnp.int32)
+    seed_times = (jnp.zeros_like(seeds) if seed_times is None
+                  else seed_times.astype(jnp.int32))
+    rows = buf.astype(jnp.int32)[jnp.maximum(seeds, 0)]         # (S, K, 3)
+    ids, eids = rows[..., 0], rows[..., 2]
+    valid = (ids >= 0) & (seeds >= 0)[:, None]  # seed < 0: hop-2 padding
+    dt = seed_times[:, None] - rows[..., 1]   # int32 first, as time_encode
+    cols = jnp.stack([valid, dt, eids >= 0], axis=1).astype(jnp.float32)
+    idx = jnp.concatenate([jnp.maximum(ids, 0), jnp.maximum(eids, 0)], 1)
+
+    blocked = lambda shp: pl.BlockSpec(  # noqa: E731
+        shp, lambda i: (i,) + (0,) * (len(shp) - 1))
+    full = lambda a: pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)  # noqa: E731
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    lead = [
+        (jnp.pad(idx, ((0, pad), (0, 0))),
+         pl.BlockSpec((block_s, 2 * K), lambda i: (i, 0),
+                      memory_space=pltpu.SMEM)),
+        (jnp.pad(cols, ((0, pad), (0, 0), (0, 0))), blocked((block_s, 3, K))),
+        (_pad2(q.reshape(S, HD), S + pad, HDp), blocked((block_s, HDp))),
+    ]
+    rest = [(_row_table(t.reshape(t.shape[0], HD), HDp), hbm)
+            for t in (k_table, v_table)]
+    if wt_k is not None:
+        d_time = wt_k.shape[0]
+        tw = time_w.reshape(1, -1).astype(jnp.float32)
+        tb = time_b.reshape(1, -1).astype(jnp.float32)
+        wtk, wtv = (_pad2(w.reshape(d_time, HD).astype(jnp.float32),
+                          d_time, HDp) for w in (wt_k, wt_v))
+        rest += [(a, full(a)) for a in (tw, tb, wtk, wtv)]
+    DEp = 0
+    if we_k is not None:
+        d_edge = edge_feats.shape[1]
+        DEp = _round_up(d_edge)
+        wek, wev = (_pad2(w.reshape(d_edge, HD).astype(jnp.float32),
+                          DEp, HDp) for w in (we_k, we_v))
+        rest += [(_row_table(edge_feats, DEp), hbm), (wek, full(wek)),
+                 (wev, full(wev))]
+    meta = dict(S=S, pad=pad, K=K, H=H, D=D, HDp=HDp, DEp=DEp,
+                block_s=block_s, ns=(S + pad) // block_s,
+                kv_dtype=k_table.dtype,
+                e_dtype=None if we_k is None else edge_feats.dtype)
+    return lead, rest, meta
+
+
+def _staging_scratch(meta, has_edge):
+    """2-slot VMEM landing zones for the neighbor rows, and their per-slot
+    DMA semaphores (k, v[, edge]). Each row lands in its own (1, width)
+    tile, so a single-row DMA never slices a sublane tile (wider than 128
+    lanes, a (K, width) slab would be tiled (8, 128) and refuse it)."""
+    K, HDp = meta["K"], meta["HDp"]
+    scratch = [pltpu.VMEM((2, K, 1, HDp), meta["kv_dtype"]),
+               pltpu.VMEM((2, K, 1, HDp), meta["kv_dtype"])]
+    if has_edge:
+        scratch.append(pltpu.VMEM((2, K, 1, meta["DEp"]), meta["e_dtype"]))
+    return scratch, [pltpu.SemaphoreType.DMA((2,))] * (3 if has_edge else 2)
+
+
+def _unpack_tables(it, has_time: bool, has_edge: bool) -> dict:
+    """Pop the grid-invariant refs in ``_layer_inputs``' ``rest`` order."""
+    t = {"k": next(it), "v": next(it)}      # (N, 1, H*D) ANY node tables
+    if has_time:
+        t.update(tw=next(it), tb=next(it),  # (1, d_time) Bochner params
+                 wtk=next(it), wtv=next(it))  # (d_time, H*D) time proj
+    if has_edge:
+        t.update(ef=next(it),               # (E, 1, d_edge) ANY features
+                 wek=next(it), wev=next(it))  # (d_edge, H*D) edge proj
+    return t
+
+
+def _make_stager(idx_ref, t, k_scr, v_scr, e_scr, sems, *, kbuf: int,
+                 has_edge: bool):
     """Build the double-buffered per-seed DMA staging closures.
 
     Shared by the forward and backward fused-layer kernel bodies: both walk
-    the same seed blocks and need the same staged data (the packed buffer
-    row in SMEM+VMEM, the K neighbor k/v table rows, and optionally the K
-    edge-feature rows) in 2-slot scratch. Seed ids < 0 (hop-2 frontier
-    padding) are clamped for the DMA and masked out by the caller.
+    the same seed blocks and need the same staged data (the K neighbor k/v
+    table rows, and optionally the K edge-feature rows) in 2-slot scratch.
+    ``idx_ref`` holds the block's clamped neighbor ids (columns ``[0, K)``)
+    and edge ids (``[K, 2K)``) in SMEM, so every source index is known
+    before any copy starts; padding slots copy row 0 and are masked out by
+    the caller.
 
-    Returns ``(stage, wait)``: ``stage(j)`` issues seed j's DMAs into slot
-    ``j % 2``; ``wait(j)`` blocks until they have all landed.
+    Returns ``(stage, wait)``: ``stage(j)`` issues seed j's K row copies
+    back-to-back into slot ``j % 2``, all in flight at once; ``wait(j)``
+    blocks until they have all landed.
     """
-    pid = pl.program_id(0)
 
-    def row_copies(j):
+    def copies(j, kk):
         sl = j % 2
-        seed = jnp.maximum(seeds_ref[pid * block_s + j], 0)
-        return (
-            pltpu.make_async_copy(buf_hbm.at[seed], row_smem.at[sl],
-                                  sem_row.at[sl]),
-            pltpu.make_async_copy(buf_hbm.at[seed], row_vmem.at[sl],
-                                  sem_rowv.at[sl]),
-        )
+        cps = [
+            pltpu.make_async_copy(t["k"].at[idx_ref[j, kk]],
+                                  k_scr.at[sl, kk], sems[0].at[sl]),
+            pltpu.make_async_copy(t["v"].at[idx_ref[j, kk]],
+                                  v_scr.at[sl, kk], sems[1].at[sl]),
+        ]
+        if has_edge:
+            cps.append(pltpu.make_async_copy(
+                t["ef"].at[idx_ref[j, kbuf + kk]], e_scr.at[sl, kk],
+                sems[2].at[sl]))
+        return cps
 
-    def issue_nbrs(j):
-        """Start all K neighbor-row copies (k, v[, edge]) back-to-back so
-        they are in flight concurrently; requires row_smem[slot] landed."""
-        sl = j % 2
-
+    def for_each_copy(j, action):
         def one(kk, c):
-            nid = jnp.maximum(row_smem[sl, kk, 0], 0)  # clamp padding (-1)
-            pltpu.make_async_copy(k_hbm.at[nid], k_scr.at[sl, kk],
-                                  sem_k.at[sl]).start()
-            pltpu.make_async_copy(v_hbm.at[nid], v_scr.at[sl, kk],
-                                  sem_v.at[sl]).start()
-            if has_edge:
-                eid = jnp.maximum(row_smem[sl, kk, 2], 0)
-                pltpu.make_async_copy(ef_hbm.at[eid], e_scr.at[sl, kk],
-                                      sem_e.at[sl]).start()
-            return c
-
-        jax.lax.fori_loop(0, kbuf, one, 0)
-
-    def wait_nbrs(j):
-        sl = j % 2
-
-        def one(kk, c):
-            nid = jnp.maximum(row_smem[sl, kk, 0], 0)
-            pltpu.make_async_copy(k_hbm.at[nid], k_scr.at[sl, kk],
-                                  sem_k.at[sl]).wait()
-            pltpu.make_async_copy(v_hbm.at[nid], v_scr.at[sl, kk],
-                                  sem_v.at[sl]).wait()
-            if has_edge:
-                eid = jnp.maximum(row_smem[sl, kk, 2], 0)
-                pltpu.make_async_copy(ef_hbm.at[eid], e_scr.at[sl, kk],
-                                      sem_e.at[sl]).wait()
+            for cp in copies(j, kk):
+                action(cp)
             return c
 
         jax.lax.fori_loop(0, kbuf, one, 0)
 
     def stage(j):
-        """Issue seed j's DMAs: buffer row, then (once the scalar copy of
-        the row has landed, so neighbor indices are known) the batched
-        neighbor-row copies."""
-        row_s, row_v = row_copies(j)
-        row_s.start()
-        row_v.start()
-        row_s.wait()
-        issue_nbrs(j)
+        for_each_copy(j, lambda cp: cp.start())
 
     def wait(j):
-        _, row_v = row_copies(j)
-        row_v.wait()
-        wait_nbrs(j)
+        for_each_copy(j, lambda cp: cp.wait())
 
     return stage, wait
 
 
-def _seed_kv(sl, seed_t, row_vmem, k_scr, v_scr, e_scr,
-             tw_ref, tb_ref, wtk_ref, wtv_ref, wek_ref, wev_ref,
-             *, kbuf: int, heads: int, hdim: int,
-             has_time: bool, has_edge: bool):
-    """Rebuild one seed's biased (K, H*D) keys/values from staged scratch.
+def _seed_kv(j, cols_ref, t, k_scr, v_scr, e_scr, *, has_time: bool,
+             has_edge: bool):
+    """Rebuild seed j's biased (K, H*D) keys/values from staged scratch.
 
     Shared by the forward (to attend) and the backward (to recompute the
-    attention weights flash-style). Returns ``(k, v, phi, theta, dt, e)``
-    where ``phi = cos(theta)`` is the Bochner encoding, ``dt`` the query/
-    neighbor time deltas and ``e`` the zeroed edge-feature rows (the
-    backward reuses all three for the weight gradients).
+    attention weights flash-style). Returns ``(valid, k, v, phi, theta,
+    dt, e)``: the (K, 1) slot validity, the keys/values, the Bochner
+    encoding ``phi = cos(theta)`` of the (K, 1) query/neighbor time deltas
+    ``dt``, and the zeroed edge-feature rows ``e`` (the backward reuses the
+    last four for the weight gradients).
     """
-    k = k_scr[sl].astype(jnp.float32).reshape(kbuf, heads * hdim)
-    v = v_scr[sl].astype(jnp.float32).reshape(kbuf, heads * hdim)
+    sl = j % 2
+    c = cols_ref[j].T                   # (K, 3): valid, dt, edge valid
+    valid = c[:, 0:1]
+    slab = lambda scr: scr[sl].reshape(scr.shape[1], scr.shape[3]  # noqa: E731
+                                       ).astype(jnp.float32)
+    k = slab(k_scr)
+    v = slab(v_scr)
     phi = theta = dt = e = None
     if has_time:
-        # dt in int32 first (exactly like nn.time_encode's caller), then
-        # the Bochner encoding phi = cos(dt * w + b) on the VPU, then the
+        # The Bochner encoding phi = cos(dt * w + b) on the VPU, then the
         # (K, d_time) @ (d_time, H*D) bias matmul on the MXU.
-        dt = (seed_t - row_vmem[sl, :, 1]).astype(jnp.float32)
-        theta = dt[:, None] * tw_ref[0] + tb_ref[0]
+        dt = c[:, 1:2]
+        theta = dt * t["tw"][...] + t["tb"][...]
         phi = jnp.cos(theta)
-        k = k + phi @ wtk_ref[...]
-        v = v + phi @ wtv_ref[...]
+        k = k + _mm(phi, t["wtk"][...])
+        v = v + _mm(phi, t["wtv"][...])
     if has_edge:
-        ev = (row_vmem[sl, :, 2] >= 0).astype(jnp.float32)[:, None]
-        e = e_scr[sl].astype(jnp.float32) * ev   # zero featureless slots
-        k = k + e @ wek_ref[...]
-        v = v + e @ wev_ref[...]
-    return k, v, phi, theta, dt, e
+        e = slab(e_scr) * c[:, 2:3]     # zero featureless slots
+        k = k + _mm(e, t["wek"][...])
+        v = v + _mm(e, t["wev"][...])
+    return valid, k, v, phi, theta, dt, e
 
 
-def _masked_softmax(s, mask):
-    """Row-softmax over the last axis with fully-masked rows zeroed —
-    identical to the oracle's ``softmax`` + ``where(mask.any(), ·, 0)``."""
-    s = jnp.where(mask[None, :], s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
-    return jnp.where(mask.any(), p, 0.0)
-
-
-def _fused_layer_kernel(
-    seeds_ref,  # scalar prefetch: (S_pad,) int32 seed node ids (SMEM)
-    times_ref,  # scalar prefetch: (S_pad,) int32 seed query times (SMEM)
-    *refs,
-    scale: float, block_s: int, kbuf: int, heads: int, hdim: int,
-    has_time: bool, has_edge: bool,
-):
+def _fused_layer_kernel(idx_ref, cols_ref, q_ref, *refs, scale: float,
+                        block_s: int, kbuf: int, heads: int, hdim: int,
+                        has_time: bool, has_edge: bool):
     """Double-buffered fused gather + bias-fold + attention body.
 
-    ``refs`` unpacks (in order) the non-prefetch inputs, the output, and the
-    scratch allocated by ``fused_temporal_layer_kernel``; the exact layout
-    depends on the static ``has_time`` / ``has_edge`` flags.
+    ``idx_ref`` (bs, 2K) SMEM DMA indices, ``cols_ref`` (bs, 3, K) VMEM
+    slot columns and ``q_ref`` (bs, H*D) VMEM queries are this block's
+    seeds; ``refs`` unpacks (in order) the grid-invariant tables and
+    weights (``_unpack_tables``), the (bs, H*D) output and the staging
+    scratch from ``_staging_scratch``.
     """
     it = iter(refs)
-    q_ref = next(it)                     # (bs, H, D) VMEM
-    k_hbm = next(it)                     # (N, H, D) ANY/HBM node key table
-    v_hbm = next(it)                     # (N, H, D) ANY/HBM node value table
-    buf_hbm = next(it)                   # (Nb, K, 3) ANY/HBM packed buffer
-    tw_ref = tb_ref = wtk_ref = wtv_ref = None
-    ef_hbm = wek_ref = wev_ref = None
-    if has_time:
-        tw_ref = next(it)                # (1, d_time) VMEM Bochner freqs
-        tb_ref = next(it)                # (1, d_time) VMEM Bochner phases
-        wtk_ref = next(it)               # (d_time, H*D) VMEM key time proj
-        wtv_ref = next(it)               # (d_time, H*D) VMEM value time proj
-    if has_edge:
-        ef_hbm = next(it)                # (E, d_edge) ANY/HBM edge features
-        wek_ref = next(it)               # (d_edge, H*D) VMEM key edge proj
-        wev_ref = next(it)               # (d_edge, H*D) VMEM value edge proj
-    o_ref = next(it)                     # (bs, H, D) VMEM
-    row_smem = next(it)                  # (2, K, 3) SMEM — scalar DMA indices
-    row_vmem = next(it)                  # (2, K, 3) VMEM — vector mask/times
-    k_scr = next(it)                     # (2, K, H, D) VMEM
-    v_scr = next(it)                     # (2, K, H, D) VMEM
-    e_scr = next(it) if has_edge else None   # (2, K, d_edge) VMEM
-    sem_row = next(it)                   # DMA((2,)) — per-slot semaphores
-    sem_rowv = next(it)
-    sem_k = next(it)
-    sem_v = next(it)
-    sem_e = next(it) if has_edge else None
+    t = _unpack_tables(it, has_time, has_edge)
+    o_ref = next(it)
+    k_scr, v_scr = next(it), next(it)       # (2, K, 1, H*D) VMEM
+    e_scr = next(it) if has_edge else None  # (2, K, 1, d_edge) VMEM
+    sems = list(it)
 
-    pid = pl.program_id(0)
-    stage, wait = _make_stager(
-        seeds_ref, buf_hbm, k_hbm, v_hbm, ef_hbm,
-        row_smem, row_vmem, k_scr, v_scr, e_scr,
-        sem_row, sem_rowv, sem_k, sem_v, sem_e,
-        block_s=block_s, kbuf=kbuf, has_edge=has_edge,
-    )
+    masks = _head_masks(heads, hdim, q_ref.shape[-1])
+    stage, wait = _make_stager(idx_ref, t, k_scr, v_scr, e_scr, sems,
+                               kbuf=kbuf, has_edge=has_edge)
 
     # Prologue: stage seed 0; the loop then overlaps seed j+1's copies with
     # seed j's compute (classic 2-slot software pipeline).
@@ -314,70 +407,15 @@ def _fused_layer_kernel(
         def _():
             stage(j + 1)
 
-        sl = j % 2
         wait(j)
-
-        seed = seeds_ref[pid * block_s + j]
-        ids = row_vmem[sl, :, 0]                      # (K,)
-        mask = (ids >= 0) & (seed >= 0)               # seed < 0: hop-2 pad
-        k, v, *_ = _seed_kv(
-            sl, times_ref[pid * block_s + j], row_vmem, k_scr, v_scr, e_scr,
-            tw_ref, tb_ref, wtk_ref, wtv_ref, wek_ref, wev_ref,
-            kbuf=kbuf, heads=heads, hdim=hdim,
-            has_time=has_time, has_edge=has_edge,
-        )
-        k = k.reshape(kbuf, heads, hdim)
-        v = v.reshape(kbuf, heads, hdim)
-
-        q = q_ref[j].astype(jnp.float32) * scale      # (H, D)
-        s = jnp.einsum("hd,khd->hk", q, k)            # (H, K)
-        p = _masked_softmax(s, mask)
-        o_ref[j] = jnp.einsum("hk,khd->hd", p, v).astype(o_ref.dtype)
+        valid, k, v, *_ = _seed_kv(j, cols_ref, t, k_scr, v_scr, e_scr,
+                                   has_time=has_time, has_edge=has_edge)
+        q = q_ref[pl.ds(j, 1), :].astype(jnp.float32) * scale
+        o_ref[pl.ds(j, 1), :] = _attend(q, k, v, valid, masks
+                                        ).astype(o_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, block_s, per_seed, 0)
-
-
-def _layer_operands(q, k_table, v_table, buf, time_w, time_b, wt_k, wt_v,
-                    edge_feats, we_k, we_v, H, D):
-    """Assemble the shared (operands, in_specs, scratch) for the fused
-    forward/backward pallas_calls: node tables + packed buffer in ANY/HBM,
-    weight groups reshaped to (d, H*D) f32 and VMEM-resident."""
-    has_time = wt_k is not None
-    has_edge = we_k is not None
-    K = buf.shape[1]
-    full = lambda a: pl.BlockSpec(a.shape, lambda i, *_: (0,) * a.ndim)  # noqa: E731
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-    ]
-    operands = [k_table, v_table, buf]
-    if has_time:
-        tw = time_w.reshape(1, -1).astype(jnp.float32)
-        tb = time_b.reshape(1, -1).astype(jnp.float32)
-        wtk = wt_k.reshape(wt_k.shape[0], H * D).astype(jnp.float32)
-        wtv = wt_v.reshape(wt_v.shape[0], H * D).astype(jnp.float32)
-        in_specs += [full(tw), full(tb), full(wtk), full(wtv)]
-        operands += [tw, tb, wtk, wtv]
-    if has_edge:
-        wek = we_k.reshape(we_k.shape[0], H * D).astype(jnp.float32)
-        wev = we_v.reshape(we_v.shape[0], H * D).astype(jnp.float32)
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY), full(wek),
-                     full(wev)]
-        operands += [edge_feats, wek, wev]
-
-    scratch = [
-        pltpu.SMEM((2, K, 3), jnp.int32),
-        pltpu.VMEM((2, K, 3), jnp.int32),
-        pltpu.VMEM((2, K, H, D), k_table.dtype),
-        pltpu.VMEM((2, K, H, D), v_table.dtype),
-    ]
-    if has_edge:
-        scratch.append(pltpu.VMEM((2, K, edge_feats.shape[1]),
-                                  edge_feats.dtype))
-    scratch += [pltpu.SemaphoreType.DMA((2,))] * (5 if has_edge else 4)
-    return operands, in_specs, scratch
 
 
 def fused_temporal_layer_kernel(
@@ -403,64 +441,41 @@ def fused_temporal_layer_kernel(
         we_v: (d_edge, H*D) edge-feature slices of the projections.
 
     Returns (S, H, D). The (S, K, H, D) gathered k/v exist only as 2-slot
-    (K, H, D) VMEM scratch, never in HBM; per-seed DMAs are double-buffered.
+    (K, H*D) VMEM scratch, never in HBM; per-seed DMAs are double-buffered.
     """
-    S, H, D = q.shape
-    K = buf.shape[1]
-    scale = scale if scale is not None else 1.0 / np.sqrt(D)
     has_time = wt_k is not None
     has_edge = we_k is not None
-
-    seeds = seeds.astype(jnp.int32)
-    seed_times = (jnp.zeros_like(seeds) if seed_times is None
-                  else seed_times.astype(jnp.int32))
-    buf = buf.astype(jnp.int32)
-    block_s = min(block_s, S)
-    pad = (-S) % block_s
-    if pad:
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-        seeds = jnp.pad(seeds, (0, pad))
-        seed_times = jnp.pad(seed_times, (0, pad))
-    ns = (S + pad) // block_s
-
-    operands, in_specs, scratch = _layer_operands(
-        q, k_table, v_table, buf, time_w, time_b, wt_k, wt_v,
-        edge_feats, we_k, we_v, H, D)
-    in_specs = [pl.BlockSpec((block_s, H, D), lambda i, *_: (i, 0, 0))
-                ] + in_specs
-    operands = [q] + operands
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ns,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_s, H, D), lambda i, *_: (i, 0, 0)),
-        scratch_shapes=scratch,
-    )
+    lead, rest, m = _layer_inputs(q, k_table, v_table, seeds, seed_times,
+                                  buf, time_w, time_b, wt_k, wt_v,
+                                  edge_feats, we_k, we_v, block_s)
+    S, H, D, HDp, bs = m["S"], m["H"], m["D"], m["HDp"], m["block_s"]
+    scratch, sems = _staging_scratch(m, has_edge)
+    operands, in_specs = zip(*(lead + rest))
     out = pl.pallas_call(
         functools.partial(
-            _fused_layer_kernel, scale=scale, block_s=block_s, kbuf=K,
-            heads=H, hdim=D, has_time=has_time, has_edge=has_edge,
+            _fused_layer_kernel, scale=1.0 / np.sqrt(D) if scale is None
+            else scale, block_s=bs, kbuf=m["K"], heads=H, hdim=D,
+            has_time=has_time, has_edge=has_edge,
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S + pad, H, D), q.dtype),
+        grid=(m["ns"],),
+        in_specs=list(in_specs),
+        out_specs=pl.BlockSpec((bs, HDp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((S + m["pad"], HDp), q.dtype),
+        scratch_shapes=scratch + sems,
         interpret=interpret,
-    )(seeds, seed_times, *operands)
-    return out[:S]
+    )(*operands)
+    return out[:S, :H * D].reshape(S, H, D)
 
 
-def _fused_layer_bwd_kernel(
-    seeds_ref,  # scalar prefetch: (S_pad,) int32 seed node ids (SMEM)
-    times_ref,  # scalar prefetch: (S_pad,) int32 seed query times (SMEM)
-    *refs,
-    scale: float, block_s: int, kbuf: int, heads: int, hdim: int,
-    has_time: bool, has_edge: bool,
-):
+def _fused_layer_bwd_kernel(idx_ref, cols_ref, q_ref, g_ref, *refs,
+                            scale: float, block_s: int, kbuf: int,
+                            heads: int, hdim: int, has_time: bool,
+                            has_edge: bool):
     """Flash-style backward body: restage, recompute attention, accumulate.
 
     Per seed, the neighborhood is re-staged through the same double-buffered
     DMA pipeline as the forward, the biased k/v and attention weights are
-    recomputed in VMEM, and the chain rule is applied locally:
+    recomputed in VMEM, and the chain rule is applied locally per head:
 
       dv   = p ⊗ g              ds = p * (dp - Σ_k p·dp)     dp = g · v
       dq   = (ds · k) * scale   dk = ds ⊗ (q * scale)
@@ -473,27 +488,12 @@ def _fused_layer_bwd_kernel(
     initialized at program 0.
     """
     it = iter(refs)
-    q_ref = next(it)                     # (bs, H, D) VMEM
-    g_ref = next(it)                     # (bs, H, D) VMEM output cotangent
-    k_hbm = next(it)                     # (N, H, D) ANY node key table
-    v_hbm = next(it)                     # (N, H, D) ANY node value table
-    buf_hbm = next(it)                   # (Nb, K, 3) ANY packed buffer
-    tw_ref = tb_ref = wtk_ref = wtv_ref = None
-    ef_hbm = wek_ref = wev_ref = None
-    if has_time:
-        tw_ref = next(it)
-        tb_ref = next(it)
-        wtk_ref = next(it)
-        wtv_ref = next(it)
-    if has_edge:
-        ef_hbm = next(it)
-        wek_ref = next(it)
-        wev_ref = next(it)
+    t = _unpack_tables(it, has_time, has_edge)
     next(it)                             # dk zeros operand (aliased → dk_hbm)
     next(it)                             # dv zeros operand (aliased → dv_hbm)
-    dq_ref = next(it)                    # (bs, H, D) VMEM blocked output
-    dk_hbm = next(it)                    # (N, H, D) f32 ANY output (aliased)
-    dv_hbm = next(it)                    # (N, H, D) f32 ANY output (aliased)
+    dq_ref = next(it)                    # (bs, H*D) VMEM blocked output
+    dk_hbm = next(it)                    # (N, 1, H*D) f32 ANY output (aliased)
+    dv_hbm = next(it)                    # (N, 1, H*D) f32 ANY output (aliased)
     dtw_ref = dtb_ref = dwtk_ref = dwtv_ref = None
     dwek_ref = dwev_ref = None
     if has_time:
@@ -504,26 +504,19 @@ def _fused_layer_bwd_kernel(
     if has_edge:
         dwek_ref = next(it)              # (d_edge, H*D) resident accumulator
         dwev_ref = next(it)
-    row_smem = next(it)                  # (2, K, 3) SMEM
-    row_vmem = next(it)                  # (2, K, 3) VMEM
-    k_scr = next(it)                     # (2, K, H, D) VMEM
-    v_scr = next(it)                     # (2, K, H, D) VMEM
+    k_scr, v_scr = next(it), next(it)    # (2, K, 1, H*D) VMEM
     e_scr = next(it) if has_edge else None
-    dk_rows = next(it)                   # (K, H, D) f32 — this seed's dk
-    dv_rows = next(it)                   # (K, H, D) f32
-    rk_row = next(it)                    # (H, D) f32 read-modify-write cell
-    rv_row = next(it)                    # (H, D) f32
-    sem_row = next(it)
-    sem_rowv = next(it)
-    sem_k = next(it)
-    sem_v = next(it)
-    sem_e = next(it) if has_edge else None
+    dk_rows = next(it)                   # (K, H*D) f32 — this seed's dk
+    dv_rows = next(it)                   # (K, H*D) f32
+    rk_row = next(it)                    # (1, H*D) f32 read-modify-write cell
+    rv_row = next(it)                    # (1, H*D) f32
     sem_rk = next(it)                    # DMA — dk row read-modify-write
     sem_rv = next(it)
+    sems = list(it)                      # staging semaphores
 
-    pid = pl.program_id(0)
+    masks = _head_masks(heads, hdim, q_ref.shape[-1])
 
-    @pl.when(pid == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _():
         if has_time:
             dtw_ref[...] = jnp.zeros_like(dtw_ref)
@@ -534,12 +527,8 @@ def _fused_layer_bwd_kernel(
             dwek_ref[...] = jnp.zeros_like(dwek_ref)
             dwev_ref[...] = jnp.zeros_like(dwev_ref)
 
-    stage, wait = _make_stager(
-        seeds_ref, buf_hbm, k_hbm, v_hbm, ef_hbm,
-        row_smem, row_vmem, k_scr, v_scr, e_scr,
-        sem_row, sem_rowv, sem_k, sem_v, sem_e,
-        block_s=block_s, kbuf=kbuf, has_edge=has_edge,
-    )
+    stage, wait = _make_stager(idx_ref, t, k_scr, v_scr, e_scr, sems,
+                               kbuf=kbuf, has_edge=has_edge)
     stage(0)
 
     def per_seed(j, carry):
@@ -547,68 +536,61 @@ def _fused_layer_bwd_kernel(
         def _():
             stage(j + 1)
 
-        sl = j % 2
         wait(j)
+        valid, k, v, phi, theta, dt, e = _seed_kv(
+            j, cols_ref, t, k_scr, v_scr, e_scr,
+            has_time=has_time, has_edge=has_edge)
+        qs = q_ref[pl.ds(j, 1), :].astype(jnp.float32) * scale   # (1, H*D)
+        g = g_ref[pl.ds(j, 1), :].astype(jnp.float32)            # (1, H*D)
 
-        seed = seeds_ref[pid * block_s + j]
-        ids = row_vmem[sl, :, 0]
-        mask = (ids >= 0) & (seed >= 0)
-        k, v, phi, theta, dt, e = _seed_kv(
-            sl, times_ref[pid * block_s + j], row_vmem, k_scr, v_scr, e_scr,
-            tw_ref, tb_ref, wtk_ref, wtv_ref, wek_ref, wev_ref,
-            kbuf=kbuf, heads=heads, hdim=hdim,
-            has_time=has_time, has_edge=has_edge,
-        )
-        k3 = k.reshape(kbuf, heads, hdim)
-        v3 = v.reshape(kbuf, heads, hdim)
-
-        qs = q_ref[j].astype(jnp.float32) * scale     # (H, D)
-        s = jnp.einsum("hd,khd->hk", qs, k3)          # (H, K)
-        p = _masked_softmax(s, mask)                  # (H, K)
-
-        g = g_ref[j].astype(jnp.float32)              # (H, D)
-        dv3 = p.T[:, :, None] * g[None]               # (K, H, D) = p ⊗ g
-        dp = jnp.einsum("hd,khd->hk", g, v3)          # (H, K)
-        ds = p * (dp - (p * dp).sum(axis=-1, keepdims=True))
-        dq_ref[j] = (jnp.einsum("hk,khd->hd", ds, k3) * scale
-                     ).astype(dq_ref.dtype)
-        dk3 = ds.T[:, :, None] * qs[None]             # (K, H, D) = ds ⊗ q
+        dq = jnp.zeros(qs.shape, jnp.float32)
+        dk = jnp.zeros(k.shape, jnp.float32)
+        dv = jnp.zeros(v.shape, jnp.float32)
+        for m in masks:
+            qm, gm = qs * m, g * m
+            p = _masked_softmax(jnp.sum(k * qm, axis=1, keepdims=True),
+                                valid)                           # (K, 1)
+            dp = jnp.sum(v * gm, axis=1, keepdims=True)          # (K, 1)
+            ds = p * (dp - jnp.sum(p * dp, axis=0, keepdims=True))
+            dq = dq + jnp.sum(ds * k, axis=0, keepdims=True) * m
+            dk = dk + ds * qm                                    # ds ⊗ q
+            dv = dv + p * gm                                     # p ⊗ g
+        dq_ref[pl.ds(j, 1), :] = (dq * scale).astype(dq_ref.dtype)
 
         # p is exactly 0 on masked slots (exp underflows at -1e30), but the
         # explicit zeroing keeps clamped padding rows provably inert.
-        mf = mask.astype(jnp.float32)[:, None]
-        dkf = dk3.reshape(kbuf, heads * hdim) * mf    # (K, H*D)
-        dvf = dv3.reshape(kbuf, heads * hdim) * mf
+        dk = dk * valid                                          # (K, H*D)
+        dv = dv * valid
 
         if has_time:
-            dwtk_ref[...] += phi.T @ dkf
-            dwtv_ref[...] += phi.T @ dvf
-            dphi = (jnp.einsum("kf,tf->kt", dkf, wtk_ref[...])
-                    + jnp.einsum("kf,tf->kt", dvf, wtv_ref[...]))
-            dtheta = -jnp.sin(theta) * dphi           # (K, d_time)
-            dtw_ref[...] += (dtheta * dt[:, None]).sum(axis=0)[None]
-            dtb_ref[...] += dtheta.sum(axis=0)[None]
+            dwtk_ref[...] += _mm(phi, dk, ((0,), (0,)))
+            dwtv_ref[...] += _mm(phi, dv, ((0,), (0,)))
+            dphi = (_mm(dk, t["wtk"][...], ((1,), (1,)))
+                    + _mm(dv, t["wtv"][...], ((1,), (1,))))
+            dtheta = -jnp.sin(theta) * dphi                      # (K, d_time)
+            dtw_ref[...] += jnp.sum(dtheta * dt, axis=0, keepdims=True)
+            dtb_ref[...] += jnp.sum(dtheta, axis=0, keepdims=True)
         if has_edge:
-            dwek_ref[...] += e.T @ dkf                # e already eid-zeroed
-            dwev_ref[...] += e.T @ dvf
+            dwek_ref[...] += _mm(e, dk, ((0,), (0,)))  # e already eid-zeroed
+            dwev_ref[...] += _mm(e, dv, ((0,), (0,)))
 
         # Scatter this seed's dk/dv rows into the table gradients: one
         # sequential read-modify-write per slot (no TPU atomics; duplicate
         # ids within a row accumulate correctly because each RMW completes
         # before the next starts).
-        dk_rows[...] = dkf.reshape(kbuf, heads, hdim)
-        dv_rows[...] = dvf.reshape(kbuf, heads, hdim)
+        dk_rows[...] = dk
+        dv_rows[...] = dv
 
         def rmw(kk, c):
-            nid = jnp.maximum(row_smem[sl, kk, 0], 0)
+            nid = idx_ref[j, kk]
             in_k = pltpu.make_async_copy(dk_hbm.at[nid], rk_row, sem_rk)
             in_v = pltpu.make_async_copy(dv_hbm.at[nid], rv_row, sem_rv)
             in_k.start()
             in_v.start()
             in_k.wait()
             in_v.wait()
-            rk_row[...] = rk_row[...] + dk_rows[kk]
-            rv_row[...] = rv_row[...] + dv_rows[kk]
+            rk_row[...] = rk_row[...] + dk_rows[pl.ds(kk, 1), :]
+            rv_row[...] = rv_row[...] + dv_rows[pl.ds(kk, 1), :]
             out_k = pltpu.make_async_copy(rk_row, dk_hbm.at[nid], sem_rk)
             out_v = pltpu.make_async_copy(rv_row, dv_hbm.at[nid], sem_rv)
             out_k.start()
@@ -634,7 +616,7 @@ def fused_temporal_layer_bwd_kernel(
 
     g: (S, H, D) cotangent of the forward output; remaining arguments as in
     the forward. Returns a dict of f32 gradients in the kernel's internal
-    layout — ``q`` (S, H, D), ``k_table``/``v_table`` (N, H, D), and, when
+    layout — ``q`` (S, H*D), ``k_table``/``v_table`` (N, H*D), and, when
     the bias groups are present, ``time_w``/``time_b`` (1, d_time) and
     ``wt_k``/``wt_v``/``we_k``/``we_v`` (d, H*D) — the caller
     (``ops._fused_layer_bwd``) reshapes/casts them back to the primal
@@ -644,102 +626,80 @@ def fused_temporal_layer_bwd_kernel(
     The grid is declared sequential ("arbitrary") so the per-row DMA
     read-modify-write scatter into dk_table/dv_table is race-free.
     """
-    S, H, D = q.shape
-    N = k_table.shape[0]
-    K = buf.shape[1]
-    scale = scale if scale is not None else 1.0 / np.sqrt(D)
     has_time = wt_k is not None
     has_edge = we_k is not None
-
-    seeds = seeds.astype(jnp.int32)
-    seed_times = (jnp.zeros_like(seeds) if seed_times is None
-                  else seed_times.astype(jnp.int32))
-    buf = buf.astype(jnp.int32)
-    block_s = min(block_s, S)
-    pad = (-S) % block_s
-    if pad:
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-        g = jnp.pad(g, ((0, pad), (0, 0), (0, 0)))
-        seeds = jnp.pad(seeds, (0, pad))
-        seed_times = jnp.pad(seed_times, (0, pad))
-    ns = (S + pad) // block_s
-
-    operands, in_specs, scratch = _layer_operands(
-        q, k_table, v_table, buf, time_w, time_b, wt_k, wt_v,
-        edge_feats, we_k, we_v, H, D)
-    blocked = pl.BlockSpec((block_s, H, D), lambda i, *_: (i, 0, 0))
-    in_specs = [blocked, blocked] + in_specs
-    operands = [q, g] + operands
+    lead, rest, m = _layer_inputs(q, k_table, v_table, seeds, seed_times,
+                                  buf, time_w, time_b, wt_k, wt_v,
+                                  edge_feats, we_k, we_v, block_s)
+    S, H, D, HDp, bs = m["S"], m["H"], m["D"], m["HDp"], m["block_s"]
+    HD, K, N = H * D, m["K"], k_table.shape[0]
+    blocked = pl.BlockSpec((bs, HDp), lambda i: (i, 0))
+    g2 = _pad2(g.reshape(S, HD), S + m["pad"], HDp)
     # Zero operands aliased to the table-gradient outputs: the kernel
     # accumulates into them by DMA read-modify-write.
-    zeros = jnp.zeros((N, H, D), jnp.float32)
-    alias_base = 2 + len(in_specs)  # operand index incl. 2 scalar-prefetch
-    in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                 pl.BlockSpec(memory_space=pltpu.ANY)]
-    operands += [zeros, zeros]
+    zeros = jnp.zeros((N, 1, HDp), jnp.float32)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands, in_specs = zip(*(lead + [(g2, blocked)] + rest
+                               + [(zeros, hbm), (zeros, hbm)]))
+    alias_base = len(operands) - 2
 
     names = ["q", "k_table", "v_table"]
     out_shape = [
-        jax.ShapeDtypeStruct((S + pad, H, D), jnp.float32),
-        jax.ShapeDtypeStruct((N, H, D), jnp.float32),
-        jax.ShapeDtypeStruct((N, H, D), jnp.float32),
+        jax.ShapeDtypeStruct((S + m["pad"], HDp), jnp.float32),
+        jax.ShapeDtypeStruct((N, 1, HDp), jnp.float32),
+        jax.ShapeDtypeStruct((N, 1, HDp), jnp.float32),
     ]
-    out_specs = [blocked, pl.BlockSpec(memory_space=pltpu.ANY),
-                 pl.BlockSpec(memory_space=pltpu.ANY)]
-    resident = lambda shp: pl.BlockSpec(shp, lambda i, *_: (0, 0))  # noqa: E731
+    out_specs = [blocked, hbm, hbm]
+    resident = lambda shp: pl.BlockSpec(shp, lambda i: (0, 0))  # noqa: E731
     if has_time:
         d_time = time_w.size
         for name, shp in (("time_w", (1, d_time)), ("time_b", (1, d_time)),
-                          ("wt_k", (d_time, H * D)), ("wt_v", (d_time, H * D))):
+                          ("wt_k", (d_time, HDp)), ("wt_v", (d_time, HDp))):
             names.append(name)
             out_shape.append(jax.ShapeDtypeStruct(shp, jnp.float32))
             out_specs.append(resident(shp))
     if has_edge:
-        d_edge = edge_feats.shape[1]
         for name in ("we_k", "we_v"):
             names.append(name)
-            out_shape.append(jax.ShapeDtypeStruct((d_edge, H * D),
+            out_shape.append(jax.ShapeDtypeStruct((m["DEp"], HDp),
                                                   jnp.float32))
-            out_specs.append(resident((d_edge, H * D)))
+            out_specs.append(resident((m["DEp"], HDp)))
 
-    # The scratch list from _layer_operands ends with the staging
-    # semaphores; the body unpacks buffers first, then semaphores, so the
-    # read-modify-write scratch slots in between and its semaphores at the
-    # end.
-    n_sems = 5 if has_edge else 4
-    scratch = (
-        scratch[:-n_sems]
-        + [
-            pltpu.VMEM((K, H, D), jnp.float32),   # dk_rows
-            pltpu.VMEM((K, H, D), jnp.float32),   # dv_rows
-            pltpu.VMEM((H, D), jnp.float32),      # rk_row
-            pltpu.VMEM((H, D), jnp.float32),      # rv_row
-        ]
-        + scratch[-n_sems:]
-        + [pltpu.SemaphoreType.DMA,               # sem_rk
-           pltpu.SemaphoreType.DMA]               # sem_rv
-    )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ns,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
+    scratch, sems = _staging_scratch(m, has_edge)
+    scratch += [
+        pltpu.VMEM((K, HDp), jnp.float32),   # dk_rows
+        pltpu.VMEM((K, HDp), jnp.float32),   # dv_rows
+        pltpu.VMEM((1, HDp), jnp.float32),   # rk_row
+        pltpu.VMEM((1, HDp), jnp.float32),   # rv_row
+        pltpu.SemaphoreType.DMA,             # sem_rk
+        pltpu.SemaphoreType.DMA,             # sem_rv
+    ]
     outs = pl.pallas_call(
         functools.partial(
-            _fused_layer_bwd_kernel, scale=scale, block_s=block_s, kbuf=K,
-            heads=H, hdim=D, has_time=has_time, has_edge=has_edge,
+            _fused_layer_bwd_kernel, scale=1.0 / np.sqrt(D) if scale is None
+            else scale, block_s=bs, kbuf=K, heads=H, hdim=D,
+            has_time=has_time, has_edge=has_edge,
         ),
-        grid_spec=grid_spec,
+        grid=(m["ns"],),
+        in_specs=list(in_specs),
+        out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=scratch + sems,
         input_output_aliases={alias_base: 1, alias_base + 1: 2},
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(seeds, seed_times, *operands)
+    )(*operands)
     grads = dict(zip(names, outs))
-    grads["q"] = grads["q"][:S]
+    grads["q"] = grads["q"][:S, :HD]
+    for name in ("k_table", "v_table"):
+        grads[name] = grads[name].reshape(N, HDp)[:, :HD]
+    for name in ("wt_k", "wt_v"):
+        if name in grads:
+            grads[name] = grads[name][:, :HD]
+    for name in ("we_k", "we_v"):
+        if name in grads:
+            grads[name] = grads[name][:edge_feats.shape[1], :HD]
     return grads
 
 

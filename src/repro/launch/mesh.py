@@ -5,7 +5,9 @@ Multi-pod: 2 x 16 x 16 = 512 chips, axes (pod, data, model); the pod axis
 extends data parallelism (and sequence sharding for long-context decode).
 
 ``make_production_mesh`` is a function — importing this module never touches
-jax device state.
+jax device state. Mesh axes are ``Auto``: the model places arrays with
+sharding constraints (``distributed/sharding.shard``), which JAX accepts
+only on ``Auto`` axes (``jax.make_mesh`` defaults to ``Explicit``).
 """
 
 from __future__ import annotations
@@ -15,10 +17,15 @@ from typing import Optional
 import jax
 
 
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: Optional[int] = None):
@@ -29,4 +36,4 @@ def make_debug_mesh(n_devices: Optional[int] = None):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))

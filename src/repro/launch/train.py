@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils.compile_cache import configure_compile_cache
+
 
 def train_tg(args) -> int:
     from repro.data import generate
@@ -209,6 +211,7 @@ def main(argv: Optional[list] = None) -> int:
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--ckpt-every", type=int, default=20)
     args = p.parse_args(argv)
+    configure_compile_cache()
     if args.workload == "tg":
         return train_tg(args)
     if args.workload == "dtdg":

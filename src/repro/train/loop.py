@@ -31,9 +31,10 @@ Pipeline surface (duck-typed, consumed by ``TrainLoop``):
 
 from __future__ import annotations
 
+import contextlib
 import time
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -568,11 +569,7 @@ class CTDGLinkPipeline:
         """
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.sharding import (
-            SHARD_MAP_KW,
-            shard_map,
-            sync_state_masked_psum,
-        )
+        from repro.distributed.sharding import sync_state_masked_psum
         from repro.models.tg.common import bce_link_loss_parts
 
         mesh = self._mesh
@@ -641,13 +638,13 @@ class CTDGLinkPipeline:
                 return scores(params, batch=pb, batch_size=Bl, **kw)
 
             if kind == "train":
-                smapped = shard_map(
+                smapped = jax.shard_map(
                     train_body, mesh=mesh, in_specs=(rep, rep, specs),
-                    out_specs=(rep, rep, rep), **SHARD_MAP_KW)
+                    out_specs=(rep, rep, rep), check_vma=False)
                 return jax.jit(lambda p, o, b: smapped(p, o, prep(b)))
-            smapped = shard_map(
+            smapped = jax.shard_map(
                 eval_body, mesh=mesh, in_specs=(rep, specs),
-                out_specs=(P(daxis), P(daxis)), **SHARD_MAP_KW)
+                out_specs=(P(daxis), P(daxis)), check_vma=False)
             return jax.jit(lambda p, b: smapped(p, prep(b)))
 
         score_fn = tgn.link_scores
@@ -686,13 +683,13 @@ class CTDGLinkPipeline:
             return (pos, neg), new_state
 
         if kind == "train":
-            smapped = shard_map(
+            smapped = jax.shard_map(
                 train_body, mesh=mesh, in_specs=(rep, rep, rep, specs),
-                out_specs=(rep, rep, rep, rep), **SHARD_MAP_KW)
+                out_specs=(rep, rep, rep, rep), check_vma=False)
             return jax.jit(lambda p, o, s, b: smapped(p, o, s, prep(b)))
-        smapped = shard_map(
+        smapped = jax.shard_map(
             eval_body, mesh=mesh, in_specs=(rep, rep, specs),
-            out_specs=((P(daxis), P(daxis)), rep), **SHARD_MAP_KW)
+            out_specs=((P(daxis), P(daxis)), rep), check_vma=False)
         return jax.jit(lambda p, s, b: smapped(p, s, prep(b)))
 
     def _build_steps_2d(self):
@@ -793,6 +790,26 @@ class CTDGLinkPipeline:
         self._place_replicated()
         return step
 
+    @property
+    def train_step(self):
+        """The train step ``train_epoch`` applies to each batch:
+        ``(params, opt_state, batch) -> (params, opt_state, loss)``, with
+        the model state after ``opt_state`` in and out for TGN/TPNet. It
+        is pure, so a caller may step from states of its own (e.g. to
+        compare two meshes from shared parameters). Single-device steps
+        are ``jax.jit`` functions and can be ``.lower()``-ed."""
+        return self._train_step
+
+    def train_batches(self) -> Iterator[Dict[str, Any]]:
+        """Yield the train split's batches as the train step consumes them
+        (hooks run, prefetched and staged on the device), continuing from
+        the current hook state; call ``reset_epoch_state`` first for a
+        fresh epoch. Closing the generator stops the prefetch thread."""
+        with self.manager.activate(TRAIN_KEY), contextlib.closing(
+                iter(self._loader(self.train_data))) as batches:
+            for batch in batches:
+                yield self._batch_tensors(batch)
+
     def train_epoch(self) -> Tuple[float, float]:
         """One epoch over the train split. Returns (mean loss, seconds)."""
         tel = self.telemetry
@@ -800,23 +817,21 @@ class CTDGLinkPipeline:
             self.reset_epoch_state()
             t0 = time.perf_counter()
             losses = []
-            with self.manager.activate(TRAIN_KEY):
-                for batch in self._loader(self.train_data):
-                    bt = self._batch_tensors(batch)
-                    # Dispatch time only: the jitted step is async, so the
-                    # span bounds Python+dispatch; device time shows up as
-                    # the next batch's wait (see docs/observability.md).
-                    with tel.span("ctdg/step"):
-                        if self.model_name in CTDG_STATELESS:
-                            self.params, self.opt_state, loss = \
-                                self._train_step(
-                                    self.params, self.opt_state, bt)
-                        else:
-                            (self.params, self.opt_state, self.model_state,
-                             loss) = self._train_step(
-                                self.params, self.opt_state,
-                                self.model_state, bt)
-                    losses.append(loss)
+            for bt in self.train_batches():
+                # Dispatch time only: the jitted step is async, so the
+                # span bounds Python+dispatch; device time shows up as
+                # the next batch's wait (see docs/observability.md).
+                with tel.span("ctdg/step"):
+                    if self.model_name in CTDG_STATELESS:
+                        self.params, self.opt_state, loss = \
+                            self._train_step(
+                                self.params, self.opt_state, bt)
+                    else:
+                        (self.params, self.opt_state, self.model_state,
+                         loss) = self._train_step(
+                            self.params, self.opt_state,
+                            self.model_state, bt)
+                losses.append(loss)
             losses = [float(l) for l in losses]
             mean, secs = float(np.mean(losses)), time.perf_counter() - t0
             sp["loss"], sp["steps"] = mean, len(losses)
@@ -1152,6 +1167,16 @@ class DTDGLinkPipeline(SnapshotPairPipeline):
                                  self.model_state, xs)
         self._cursor = chi
         return [float(l) for l in np.asarray(ls)]
+
+    def lower_train_chunk(self):
+        """The first train chunk's scan (the whole split by default), as
+        ``train_chunk`` runs it, lowered from the current state
+        (``jax.stages.Lowered``): to see what an epoch compiles to."""
+        lo, hi = self._split_pairs("train")
+        chi = min(lo + (self.chunk_size or max(hi - lo, 1)), hi)
+        return self._train_scan.lower(
+            self.params, self.opt_state, self.model_state,
+            self._pair_xs(lo, chi, self.num_negatives))
 
     def train_epoch(self) -> Tuple[float, float]:
         """One epoch over the train split. Returns (mean loss, seconds).

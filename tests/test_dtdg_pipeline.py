@@ -126,6 +126,17 @@ def test_scan_chunked_matches_whole_epoch(small_stream):
     assert whole.evaluate("val")[0] == chunked.evaluate("val")[0]
 
 
+def test_lower_train_chunk_is_the_first_chunk(small_stream):
+    """The lowered scan is the one ``train_chunk`` runs: one loss per pair
+    of the first chunk, compiled as a loop."""
+    tr = SnapshotLinkTrainer("gcn", small_stream, snapshot_unit="h",
+                             d_embed=16, chunk_size=2)
+    lowered = tr.lower_train_chunk()
+    assert lowered.out_info[1].shape == (2,)
+    assert len(tr.train_chunk()) == 2
+    assert "while" in lowered.compile().as_text()
+
+
 def test_empty_val_split_keeps_test_pairs(small_stream):
     """val_ratio=0 collapses val onto the test boundary instead of
     silently swallowing the test split (regression)."""
