@@ -273,7 +273,7 @@ def tgat_phase(args, on_tpu: bool) -> None:
     t0 = time.perf_counter()
     val_mrr, secs = pipe.evaluate("val")
     n_eval = sum(1 for r in sink.records if r.get("kind") == "span"
-                 and r["name"] == "ctdg/eval_step")
+                 and r["name"] == "ctdg/eval_dispatch")
     log(f"[tgat] evaluate(val): MRR {val_mrr!r} over {n_eval} batches, "
         f"val loop {secs!r} s (call incl. warm-up "
         f"{time.perf_counter() - t0!r} s)")

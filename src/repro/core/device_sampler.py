@@ -49,11 +49,15 @@ results are bit-identical to the single-device path (property-tested under
 ``--xla_force_host_platform_device_count=8``). ``state_dict`` always emits
 the canonical host layout (sinks and padding stripped), so checkpoints
 reshard transparently across mesh sizes in both directions.
+
+**Program names.** Every variant (donated or copying, single-device or
+sharded) compiles as ``jit_sampler_update`` and ``jit_sampler_sample``:
+the names of the sampler's device programs in a profiler trace, which the
+benchmark's per-layer readers look for (``docs/observability.md``).
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
@@ -162,21 +166,17 @@ def _insert_stream(state, nodes, ok, vals, *, k: int):
     return {"buf": buf, "cc": cc}
 
 
-def _update_impl(state, src, dst, t, eids, valid, *, k: int, directed: bool):
+def sampler_update(state, src, dst, t, eids, valid, *, k: int,
+                   directed: bool):
     """Insert a time-ordered batch into the circular buffers. Pure/jit."""
     nodes, ok, vals = _event_stream(src, dst, t, eids, valid,
                                     directed=directed)
     return _insert_stream(state, nodes, ok, vals, k=k)
 
 
-@partial(jax.jit, static_argnames=("k", "directed"), donate_argnums=(0,))
-def _update_donated(state, src, dst, t, eids, valid, *, k, directed):
-    return _update_impl(state, src, dst, t, eids, valid, k=k, directed=directed)
-
-
-@partial(jax.jit, static_argnames=("k", "directed"))
-def _update_copying(state, src, dst, t, eids, valid, *, k, directed):
-    return _update_impl(state, src, dst, t, eids, valid, k=k, directed=directed)
+_update_donated = jax.jit(sampler_update, static_argnames=("k", "directed"),
+                          donate_argnums=(0,))
+_update_copying = jax.jit(sampler_update, static_argnames=("k", "directed"))
 
 
 def _update(state, src, dst, t, eids, valid, *, k: int, directed: bool,
@@ -212,11 +212,13 @@ def _finish_sample(rows, cc, *, k: int):
     return ids, times, eids, mask
 
 
-@partial(jax.jit, static_argnames=("k",))
-def _sample(state, seeds, *, k: int):
+def sampler_sample(state, seeds, *, k: int):
     """Gather the K most recent neighbors per seed, most-recent-first."""
     rows, cc = _gather_rows(state, seeds, k=k)
     return _finish_sample(rows, cc, k=k)
+
+
+_sample = jax.jit(sampler_sample, static_argnames=("k",))
 
 
 class DeviceRecencySampler:
@@ -292,7 +294,7 @@ class DeviceRecencySampler:
         state_specs = {"buf": P(axis), "cc": P(axis)}
         rep = P()
 
-        def update_body(state, src, dst, t, eids, valid):
+        def sampler_update(state, src, dst, t, eids, valid):
             lo = jax.lax.axis_index(axis).astype(jnp.int32) * per
             nodes, ok, vals = _event_stream(src, dst, t, eids, valid,
                                             directed=directed)
@@ -309,16 +311,19 @@ class DeviceRecencySampler:
             cc = jnp.where(owned[:, None], cc, 0)
             return (jax.lax.psum(rows, axis), jax.lax.psum(cc, axis))
 
-        upd = jax.shard_map(update_body, mesh=mesh,
+        upd = jax.shard_map(sampler_update, mesh=mesh,
                             in_specs=(state_specs, rep, rep, rep, rep, rep),
                             out_specs=state_specs, check_vma=False)
         smp = jax.shard_map(sample_body, mesh=mesh,
                             in_specs=(state_specs, rep),
                             out_specs=(rep, rep), check_vma=False)
+
+        def sampler_sample(state, seeds):
+            return _finish_sample(*smp(state, seeds), k=k)
+
         self._sharded_update_donated = jax.jit(upd, donate_argnums=(0,))
         self._sharded_update_copying = jax.jit(upd)
-        self._sharded_sample = jax.jit(
-            lambda state, seeds: _finish_sample(*smp(state, seeds), k=k))
+        self._sharded_sample = jax.jit(sampler_sample)
 
     def _install_canonical(self, buf: Optional[np.ndarray],
                            cc: Optional[np.ndarray]) -> None:

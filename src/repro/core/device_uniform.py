@@ -42,11 +42,14 @@ uniform draws see the same bounds as the single-device path — then the
 gathered rows). Draws are bit-identical to the single-device sampler at
 any shard count; ``state_dict`` always reassembles the canonical host CSR,
 so checkpoints reshard across mesh sizes in both directions.
+
+**Program names.** The build compiles as ``jit_sampler_build`` and every
+sample variant as ``jit_sampler_sample``, the recency sampler's names
+(``core/device_sampler.py``).
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
@@ -59,8 +62,7 @@ from repro.core.sampler import NeighborBlock, csr_from_state
 _I32_MAX = np.int32(2**31 - 1)
 
 
-@partial(jax.jit, static_argnames=("num_nodes",))
-def _build(nodes, nbrs, times, eids, *, num_nodes: int):
+def sampler_build(nodes, nbrs, times, eids, *, num_nodes: int):
     """Sort the doubled edge list into node-major/time-ascending CSR order
     and compute per-node extents with segment ops. Pure/jit."""
     m = nodes.shape[0]
@@ -90,8 +92,10 @@ def _build(nodes, nbrs, times, eids, *, num_nodes: int):
     }
 
 
-@partial(jax.jit, static_argnames=("k",))
-def _sample(adj, seeds, query_t, rng_key, *, k: int):
+_build = jax.jit(sampler_build, static_argnames=("num_nodes",))
+
+
+def sampler_sample(adj, seeds, query_t, rng_key, *, k: int):
     """Uniform K-with-replacement draws from each seed's strict-past prefix.
 
     One global ``searchsorted`` on the composite key yields every seed's
@@ -114,6 +118,9 @@ def _sample(adj, seeds, query_t, rng_key, *, k: int):
     eids = jnp.where(has[:, None], adj["adj_e"][idx], -1)
     mask = jnp.broadcast_to(has[:, None], (B, k))
     return ids, times, eids, mask
+
+
+_sample = jax.jit(sampler_sample, static_argnames=("k",))
 
 
 class DeviceUniformSampler:
@@ -416,7 +423,7 @@ class DeviceUniformSampler:
                             in_specs=(adj_specs, rep, rep, rep),
                             out_specs=(rep, rep), check_vma=False)
 
-        def sample(adj, seeds, query_t, rng_key):
+        def sampler_sample(adj, seeds, query_t, rng_key):
             rows, n_valid = smp(adj, seeds, query_t, rng_key)
             has = n_valid > 0
             ids = jnp.where(has[:, None], rows[..., 0], -1)
@@ -425,7 +432,7 @@ class DeviceUniformSampler:
             mask = jnp.broadcast_to(has[:, None], (seeds.shape[0], k))
             return ids, times, eids, mask
 
-        self._sharded_sample = jax.jit(sample)
+        self._sharded_sample = jax.jit(sampler_sample)
 
     @property
     def _built(self) -> bool:

@@ -10,7 +10,9 @@ topological order.
 The ``HookManager`` owns hook state, resolves the ordering once at build
 time (invalid recipes fail fast with a precise diagnostic), supports keyed
 activation groups (e.g. ``train`` vs ``eval`` hooks), and exposes a single
-``reset_state`` for all stateful hooks. The hook/recipe formalism and the
+``reset_state`` for all stateful hooks. With an enabled ``telemetry`` each
+``execute`` is a ``hooks`` span holding one ``hooks/<hook name>`` span per
+hook (``docs/observability.md``). The hook/recipe formalism and the
 ``state_dict`` checkpoint contract are documented in
 ``docs/architecture.md``.
 """
@@ -161,11 +163,16 @@ class HookManager:
 
     SHARED_KEY = "shared"
 
-    def __init__(self, base_attrs: FrozenSet[str] = BASE_ATTRS):
+    def __init__(self, base_attrs: FrozenSet[str] = BASE_ATTRS,
+                 telemetry=None):
+        from repro.obs import NULL
+
         self._groups: Dict[str, List[Hook]] = {self.SHARED_KEY: []}
         self._active: Optional[str] = None
         self._order_cache: Dict[Optional[str], List[Hook]] = {}
         self._base_attrs = base_attrs
+        # A pipeline sets its own, the one its loaders emit into.
+        self.telemetry = telemetry if telemetry is not None else NULL
 
     # -- registration -------------------------------------------------------
     def register(self, hook: Hook, key: str = SHARED_KEY) -> "HookManager":
@@ -221,15 +228,18 @@ class HookManager:
         return self._order_cache[key]
 
     def execute(self, batch: Batch) -> Batch:
-        for hook in self._resolve(self._active):
-            hook.require_ok = batch.require(*hook.requires)  # runtime contract
-            batch = hook(batch)
-            missing = hook.produces - batch.attrs
-            if missing:
-                raise RecipeError(
-                    f"hook {hook.name!r} declared produces={sorted(hook.produces)} "
-                    f"but did not produce {sorted(missing)}"
-                )
+        tel = self.telemetry
+        with tel.span("hooks"):
+            for hook in self._resolve(self._active):
+                hook.require_ok = batch.require(*hook.requires)  # runtime contract
+                with tel.span("hooks/" + hook.name):
+                    batch = hook(batch)
+                missing = hook.produces - batch.attrs
+                if missing:
+                    raise RecipeError(
+                        f"hook {hook.name!r} declared produces={sorted(hook.produces)} "
+                        f"but did not produce {sorted(missing)}"
+                    )
         return batch
 
     # -- state ---------------------------------------------------------------

@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from functools import partial
 from typing import Iterator, Optional
 
@@ -366,11 +365,11 @@ class PrefetchLoader:
 
     ``telemetry`` (a ``repro.obs.Telemetry``; disabled default) makes the
     queue dynamics observable (``docs/observability.md``): a
-    ``loader/stage`` span around each producer-side hook+staging pass, a
-    ``loader/prefetch_wait`` histogram of how long the consumer blocked
-    per batch, ``loader/producer_stall`` / ``loader/consumer_stall``
-    counters (bounded-queue full on put / empty on get), a
-    ``loader/queue_depth`` gauge sampled at each dequeue, and a
+    ``loader/stage`` span around each producer-side H2D staging (the hooks
+    run before it, in ``inner``), a ``loader/wait`` span around each
+    consumer-side wait for the next batch, ``loader/producer_stall`` /
+    ``loader/consumer_stall`` counters (bounded-queue full on put / empty
+    on get), a ``loader/queue_depth`` gauge sampled at each dequeue, and a
     ``loader/batches`` counter.
 
     ``staging`` enables the reusable host staging buffers
@@ -394,6 +393,7 @@ class PrefetchLoader:
     """
 
     _END = object()
+    _STOPPED = object()
 
     def __init__(self, inner, device=None, prefetch: int = 2,
                  staging: Optional[bool] = None, telemetry=None):
@@ -452,18 +452,14 @@ class PrefetchLoader:
             except BaseException as e:  # surfaced on the consumer side
                 put_or_stop(e)
 
-        thread = threading.Thread(target=produce, daemon=True)
-        with self._active_lock:
-            self._active.append((stop, thread))
-        thread.start()
-        try:
+        def take():
+            """The next queued item, or ``_STOPPED`` once closed."""
             while True:
-                wait_t0 = time.perf_counter()
                 try:
                     item = q.get(timeout=0.2)
                 except queue.Empty:
                     if stop.is_set():  # close() mid-iteration: clean end
-                        return
+                        return self._STOPPED
                     if not thread.is_alive():
                         raise RuntimeError(
                             "PrefetchLoader producer thread died without "
@@ -471,11 +467,20 @@ class PrefetchLoader:
                     # Starvation: the producer is the bottleneck here.
                     tel.count("loader/consumer_stall")
                     continue
-                if stop.is_set():  # closed: queued batches are dropped
+                # Closed: queued batches are dropped.
+                return self._STOPPED if stop.is_set() else item
+
+        thread = threading.Thread(target=produce, daemon=True)
+        with self._active_lock:
+            self._active.append((stop, thread))
+        thread.start()
+        try:
+            while True:
+                with tel.span("loader/wait"):
+                    item = take()
+                if item is self._STOPPED:
                     return
                 if tel.enabled:
-                    tel.observe("loader/prefetch_wait",
-                                time.perf_counter() - wait_t0)
                     tel.gauge("loader/queue_depth", q.qsize())
                 if item is self._END:
                     return

@@ -593,12 +593,15 @@ class SnapshotNegativeHook(Hook):
 
 class EdgeFeatureLookupHook(Hook):
     """Produces ``<prefix>_feats``: gather stored edge features for sampled
-    neighbor edge ids (zeros where padded / featureless)."""
+    neighbor edge ids (zeros where padded / featureless). Named
+    ``EdgeFeatureLookupHook[<prefix>]``, so the hop-1 and hop-2 lookups
+    have spans of their own."""
 
     def __init__(self, edge_feats: Optional[np.ndarray], feat_dim: int,
                  prefix: str = "nbr"):
         super().__init__(
-            requires={f"{prefix}_eids"}, produces={f"{prefix}_feats"}
+            requires={f"{prefix}_eids"}, produces={f"{prefix}_feats"},
+            name=f"EdgeFeatureLookupHook[{prefix}]",
         )
         self._feats = edge_feats
         self._dim = feat_dim

@@ -8,8 +8,9 @@ implementations cover every deployment:
   * :class:`NullSink`   — drops everything; the explicit no-op. A
     ``Telemetry`` with no sinks (the default) never even builds records,
     so the disabled path costs one attribute check per call site.
-  * :class:`MemorySink` — appends to an in-process list; the test sink
-    (and what ``TrainLoop`` tees through to rebuild its history).
+  * :class:`MemorySink` — appends to an in-process list, optionally only
+    the records under some name prefixes; the test sink (and what
+    ``TrainLoop`` tees through to rebuild its history).
   * :class:`FileSink`   — appends one JSON line per record to a file
     (JSONL), flushed per record so a crashed run keeps everything it
     emitted. This is what ``TrainSpec.telemetry`` wires up.
@@ -19,7 +20,7 @@ from __future__ import annotations
 
 import json
 import threading
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional, Tuple
 
 
 class Sink:
@@ -46,15 +47,20 @@ class MemorySink(Sink):
 
     ``records`` is the emitted list in arrival order; it is safe to read
     concurrently with emits (appends are atomic under the GIL, and a lock
-    guards against torn iteration in ``drain``).
+    guards against torn iteration in ``drain``). ``prefixes`` keeps only
+    records whose ``name`` starts with one of them (all when ``None``).
     """
 
-    def __init__(self):
+    def __init__(self, prefixes: Optional[Tuple[str, ...]] = None):
         self.records: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
+        self._prefixes = prefixes
 
     def emit(self, record: Dict[str, Any]) -> None:
-        """Append the record."""
+        """Append the record (if its name is kept)."""
+        if self._prefixes is not None and not record.get(
+                "name", "").startswith(self._prefixes):
+            return
         with self._lock:
             self.records.append(record)
 

@@ -8,7 +8,9 @@ One ``Telemetry`` object is the write-side API the instrumented paths
     nest per thread (a thread-local stack turns ``name`` into the dotted
     ``path``) and emit one ``span`` record at exit; the context manager
     yields a mutable attrs dict so callers can attach results (loss,
-    metric, sizes) measured inside the span.
+    metric, sizes) measured inside the span. Each span is also a
+    ``jax.profiler.TraceAnnotation`` named ``name``, so a profiler trace
+    shows it on its host thread, on the device timeline's clock.
   * ``count(name)``   — monotone counters (queue stalls, shed requests,
     windows read), snapshotted as ``counter`` records by ``flush()``.
   * ``gauge(name)``   — last-value gauges (queue depth, EWMA latency,
@@ -26,6 +28,14 @@ be attached/detached mid-run — ``TrainLoop`` tees a ``MemorySink``
 through whatever the pipeline already has to rebuild its history from
 the records it just emitted.
 
+**Compiles.** While any ``Telemetry`` is enabled, one process-wide
+``jax.monitoring`` listener counts ``jax/compiles`` in each enabled
+instance: one per executable JAX builds for a jit-cache miss (its
+``jaxpr_to_mlir_module`` event, whether XLA or the persistent cache then
+supplies the binary). Each XLA compile also emits a ``jax/compile`` span
+record (attrs ``fun``), on the compiling thread. While none is enabled,
+no listener is registered.
+
 All aggregate state is lock-guarded and spans use thread-local nesting,
 so the serving and prefetch daemon threads emit safely into the same
 object. ``EwmaGauge`` is the standalone exponentially-weighted average
@@ -40,6 +50,7 @@ import contextlib
 import math
 import threading
 import time
+import weakref
 from typing import Any, Dict, Iterable, List, Optional
 
 from repro.obs.sinks import MemorySink, NullSink, Sink
@@ -170,12 +181,14 @@ class Telemetry:
         if not isinstance(sink, NullSink):
             with self._lock:
                 self._sinks.append(sink)
+            _compiles.track(self)
         return sink
 
     def detach(self, sink: Sink) -> None:
         """Detach a previously attached sink (missing sinks are ignored)."""
         with self._lock:
             self._sinks = [s for s in self._sinks if s is not sink]
+        _compiles.track(self)
 
     def _emit(self, record: Dict[str, Any]) -> None:
         for s in list(self._sinks):
@@ -187,28 +200,45 @@ class Telemetry:
 
         Yields a mutable attrs dict (seeded with ``**attrs``) that rides
         the emitted ``span`` record; nesting within a thread builds the
-        dotted ``path``. Disabled telemetry returns a cached null context
-        (yields a scratch dict, records nothing).
+        dotted ``path``. The section is also a profiler
+        ``TraceAnnotation`` named ``name`` (attrs stay in the record).
+        Disabled telemetry returns a cached null context (yields a scratch
+        dict, records nothing, annotates nothing).
         """
         if not self._sinks:
             return self._null_span
         return self._span(name, attrs)
 
-    @contextlib.contextmanager
-    def _span(self, name: str, attrs: Dict[str, Any]):
+    def _stack(self) -> List[str]:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
+        return stack
+
+    @contextlib.contextmanager
+    def _span(self, name: str, attrs: Dict[str, Any]):
+        from jax.profiler import TraceAnnotation
+
+        stack = self._stack()
         path = ".".join([*stack, name])
         stack.append(name)
         t0 = time.monotonic()
         try:
-            yield attrs
+            with TraceAnnotation(name):
+                yield attrs
         finally:
             dur = time.monotonic() - t0
             stack.pop()
             self._emit({"kind": "span", "name": name, "path": path,
                         "t0": t0, "dur_s": dur, "attrs": attrs})
+
+    def _ended(self, name: str, dur: float, attrs: Dict[str, Any]) -> None:
+        """Record a section that has just ended after ``dur`` seconds, as
+        a span nested under this thread's open spans."""
+        path = ".".join([*self._stack(), name])
+        self._emit({"kind": "span", "name": name, "path": path,
+                    "t0": time.monotonic() - dur, "dur_s": dur,
+                    "attrs": attrs})
 
     # -- aggregates ------------------------------------------------------
     def count(self, name: str, n: float = 1) -> None:
@@ -278,6 +308,52 @@ class Telemetry:
             return self._hists.get(name)
 
 
+class _CompileListener:
+    """The one ``jax.monitoring`` listener behind ``jax/compiles``: it is
+    registered while at least one ``Telemetry`` is enabled and counts into
+    each of them. Instances are held weakly; one collected while enabled
+    stops counting at once, and the listener goes at the next
+    attach/detach that finds no instance enabled."""
+
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._enabled: "weakref.WeakSet[Telemetry]" = weakref.WeakSet()
+        self.registered = False
+
+    def track(self, tel: "Telemetry") -> None:
+        import jax.monitoring
+
+        with self._lock:
+            if tel.enabled:
+                self._enabled.add(tel)
+            else:
+                self._enabled.discard(tel)
+            want = len(self._enabled) > 0
+            if want and not self.registered:
+                jax.monitoring.register_event_duration_secs_listener(
+                    self._on_event)
+            elif self.registered and not want:
+                jax.monitoring.unregister_event_duration_listener(
+                    self._on_event)
+            self.registered = want
+
+    def _on_event(self, event: str, secs: float, **kw) -> None:
+        if event == self.LOWER:
+            for tel in list(self._enabled):
+                tel.count("jax/compiles")
+        elif event == self.COMPILE:
+            fun = str(kw.get("fun_name", ""))
+            for tel in list(self._enabled):
+                if tel.enabled:
+                    tel._ended("jax/compile", secs, {"fun": fun})
+
+
+_compiles = _CompileListener()
+
+
 #: Shared disabled instance — the default for instrumented call sites
 #: that never attach sinks themselves (do not attach sinks to it).
 NULL = Telemetry()
@@ -288,9 +364,8 @@ def span_report(records: Iterable[Dict[str, Any]], min_pct: float = 0.5,
     """Aggregate ``span`` records into a per-path timing table.
 
     Sums duration and call count per dotted span path and renders the
-    Table-11-style breakdown the old ``utils.prof.Profiler.report``
-    printed (percentages against the top-level total; sub-``min_pct``
-    rows dropped). ``markdown=True`` renders a GitHub-flavored table for
+    Table-11-style breakdown (percentages against the top-level total;
+    sub-``min_pct`` rows dropped). ``markdown=True`` renders a GitHub-flavored table for
     ``$GITHUB_STEP_SUMMARY``. Non-span records are ignored, so a whole
     JSONL file can be piped through unfiltered.
     """

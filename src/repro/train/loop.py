@@ -200,7 +200,9 @@ class TrainLoop:
             ckpt_every: int = 0, log=None) -> Dict[str, Any]:
         """Run the epoch loop; see the class docstring for the contract."""
         tel = self.telemetry
-        mem = tel.attach(MemorySink())  # tee: history comes from records
+        # Tee: the history comes from the records (its own ones only, so
+        # the per-batch spans of a long fit are not kept in memory).
+        mem = tel.attach(MemorySink(prefixes=("train/",)))
         try:
             for epoch in range(epochs):
                 with tel.span("train/epoch", epoch=epoch) as sp:
@@ -434,6 +436,7 @@ class CTDGLinkPipeline:
             edge_feat_dim=d_edge,
             seed=seed,
         )
+        self.manager.telemetry = self.telemetry
         if spec.kind == "uniform":
             # The uniform samplers draw from a static CSR-by-time adjacency;
             # build it once over the full stream — the strict t < query_t
@@ -745,15 +748,20 @@ class CTDGLinkPipeline:
         return {k: batch[k] for k in batch.keys()}
 
     def reset_epoch_state(self):
-        """Clear hook/sampler state (+ recurrent model state) for an epoch."""
+        """Clear hook/sampler state (+ recurrent model state) for an epoch.
+
+        Fresh model state is committed where the steps leave it, with the
+        batches (the mesh's replicated sharding, else the first device),
+        so a new epoch's first step reuses the compiled step."""
         self.manager.reset_state()
         if self.model_name == "tgn":
             self.model_state = tgn.init_state(self.cfg)
         elif self.model_name == "tpnet":
             self.model_state = tpnet.init_state(self.params, self.cfg)
-        if self._mesh is not None and self.model_name in CTDG_STATEFUL:
-            self.model_state = jax.device_put(self.model_state,
-                                              self._replicated)
+        if self.model_name in CTDG_STATEFUL:
+            self.model_state = jax.device_put(
+                self.model_state, jax.devices()[0] if self._mesh is None
+                else self._replicated)
 
     # -- checkpointing ---------------------------------------------------
     # The hook/sampler buffers (host numpy or device JAX pytree — both
@@ -818,10 +826,10 @@ class CTDGLinkPipeline:
             t0 = time.perf_counter()
             losses = []
             for bt in self.train_batches():
-                # Dispatch time only: the jitted step is async, so the
-                # span bounds Python+dispatch; device time shows up as
-                # the next batch's wait (see docs/observability.md).
-                with tel.span("ctdg/step"):
+                # The jitted step is async: the span bounds Python and
+                # dispatch; device time shows up as the next batch's
+                # ``loader/wait`` (see docs/observability.md).
+                with tel.span("ctdg/dispatch"):
                     if self.model_name in CTDG_STATELESS:
                         self.params, self.opt_state, loss = \
                             self._train_step(
@@ -859,7 +867,7 @@ class CTDGLinkPipeline:
             with self.manager.activate(EVAL_KEY):
                 for batch in self._loader(data):
                     bt = self._batch_tensors(batch)
-                    with tel.span("ctdg/eval_step"):
+                    with tel.span("ctdg/eval_dispatch"):
                         if self.model_name in CTDG_STATELESS:
                             pos, neg = self._eval_step(self.params, bt)
                         else:
@@ -1031,6 +1039,7 @@ class DTDGLinkPipeline(SnapshotPairPipeline):
             seed=seed,
             device=device,
         )
+        self.manager.telemetry = self.telemetry
 
         self.opt_cfg = AdamWConfig(lr=1e-3 if lr is None else lr)
         self.opt_state = adamw_init(self.params)

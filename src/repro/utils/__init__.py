@@ -1,3 +1,2 @@
-from repro.utils.prof import Profiler, profile_section
-
-__all__ = ["Profiler", "profile_section"]
+"""Small runtime utilities (``compile_cache``: JAX's persistent compile
+cache for the chip entry points)."""

@@ -72,7 +72,6 @@ DOCUMENTED_MODULES = [
     "repro.obs.sinks",
     "repro.obs.telemetry",
     "repro.obs.profiler",
-    "repro.utils.prof",
     # Test infrastructure is public surface too: the shared kernel-parity
     # harness and the jaxpr-inspection helpers are how new kernel families
     # get their acceptance coverage.

@@ -1,6 +1,7 @@
 """Structured telemetry (``repro.obs``): span nesting + monotonic timing,
 histogram quantiles against numpy, JSONL schema round-trip through
-``FileSink``, the near-zero disabled fast path, ``TrainLoop`` history
+``FileSink``, the near-zero disabled fast path, spans as profiler trace
+events, the hook/loader/compile instrumentation, ``TrainLoop`` history
 parity with the records it emits, and the end-to-end acceptance run — one
 sink observing a CTDG epoch, a serving chaos burst, and a windowed
 out-of-core storage epoch + streaming-CSR build, every record
@@ -146,6 +147,18 @@ def test_bench_record_matches_legacy_fields():
     assert rec["backend"] == "cpu" and rec["device_count"] == 1
 
 
+def test_memory_sink_keeps_only_its_prefixes():
+    tel = Telemetry()
+    kept = tel.attach(MemorySink(prefixes=("train/",)))
+    with tel.span("train/epoch"):
+        with tel.span("hooks"):
+            pass
+    tel.count("train/steps")
+    tel.flush()
+    assert [r["name"] for r in kept.records] == ["train/epoch",
+                                                 "train/steps"]
+
+
 def test_attach_detach_tee():
     tel = Telemetry(MemorySink())
     extra = tel.attach(MemorySink())
@@ -171,6 +184,213 @@ def test_disabled_overhead_is_negligible():
     per_iter = (time.perf_counter() - t0) / n
     # one nullcontext + two early returns; generous bound for slow CI
     assert per_iter < 20e-6, f"disabled telemetry costs {per_iter:.2e}s/iter"
+
+
+# ------------------------------------------- profiler trace and compiles
+
+def _traced(fn):
+    """Run ``fn`` under a CPU ``jax.profiler`` trace; the host events as
+    ``{thread line index: [event names]}`` of the ``/host:CPU`` plane."""
+    import glob
+    import os
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    logdir = tempfile.mkdtemp(prefix="obs-trace-")
+    jax.profiler.start_trace(logdir)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                out[i] = [ev.name for ev in line.events]
+    return out
+
+
+def test_enabled_span_is_a_trace_event_on_its_thread():
+    import threading
+
+    tel = Telemetry()
+    sink = tel.attach(MemorySink())
+
+    def worker():
+        with tel.span("obs/worker"):
+            time.sleep(0.001)
+
+    def body():
+        with tel.span("obs/main", size=3):
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join()
+
+    lines = _traced(body)
+    main = [i for i, names in lines.items() if "obs/main" in names]
+    other = [i for i, names in lines.items() if "obs/worker" in names]
+    assert len(main) == 1 and len(other) == 1 and main != other
+    # The event is named by the span's name alone; attrs stay in the record.
+    assert not any("size" in n for names in lines.values() for n in names)
+    assert [r["attrs"] for r in sink.records
+            if r["name"] == "obs/main"] == [{"size": 3}]
+
+
+def test_disabled_span_is_the_null_context_and_no_trace_event():
+    tel = Telemetry()
+    assert tel.span("obs/off") is tel.span("obs/other")  # cached
+
+    def body():
+        with tel.span("obs/off"):
+            pass
+
+    lines = _traced(body)
+    assert not any("obs/off" in names for names in lines.values())
+
+
+def test_hook_manager_spans_each_hook_inside_hooks():
+    from repro.core.batch import Batch
+    from repro.core.hooks import HookManager, LambdaHook
+
+    def add(attr):
+        def fn(b):
+            b[attr] = b["src"] + 1
+            return b
+        return fn
+
+    tel = Telemetry()
+    sink = tel.attach(MemorySink())
+    m = HookManager(telemetry=tel)
+    m.register(LambdaHook(add("a"), produces=["a"], name="first"))
+    m.register(LambdaHook(add("b"), requires=["a"], produces=["b"],
+                          name="second"))
+    batch = Batch({"src": np.arange(3), "dst": np.arange(3),
+                   "time": np.arange(3)})
+    m.execute(batch)
+    m.execute(batch)
+    paths = [r["path"] for r in sink.records]
+    assert paths == ["hooks.hooks/first", "hooks.hooks/second", "hooks"] * 2
+    # Without telemetry the manager runs the same hooks and records nothing.
+    assert "b" in HookManager().register_all(m.hooks()).execute(batch).attrs
+
+
+def test_compiles_counts_a_fresh_jit_and_not_a_cached_call():
+    import jax
+    import jax.numpy as jnp
+
+    x, y = jnp.arange(7.0), jnp.arange(8.0)
+    tel = Telemetry()
+    sink = tel.attach(MemorySink())
+
+    @jax.jit
+    def fresh_fn(a):
+        return a * 3.0 + 1.0
+
+    fresh_fn(x).block_until_ready()
+    assert tel.counter_value("jax/compiles") == 1
+    (rec,) = [r for r in sink.records if r["name"] == "jax/compile"]
+    assert rec["attrs"] == {"fun": "jit(fresh_fn)"} and rec["dur_s"] > 0
+    validate(rec)
+    fresh_fn(x).block_until_ready()  # cached: nothing new
+    assert tel.counter_value("jax/compiles") == 1
+    fresh_fn(y).block_until_ready()  # a new shape compiles
+    assert tel.counter_value("jax/compiles") == 2
+
+
+def test_compile_listener_is_registered_only_while_enabled():
+    import jax
+
+    from repro.obs.telemetry import _compiles
+
+    tel = Telemetry()
+    sink = MemorySink()
+    tel.attach(sink)
+    assert _compiles.registered
+    tel.detach(sink)
+    assert not _compiles.registered
+
+    @jax.jit
+    def unseen(a):
+        return a - 2.0
+
+    unseen(np.ones(5, np.float32))
+    tel.attach(sink)
+    assert tel.counter_value("jax/compiles") == 0  # compiled while off
+    tel.detach(sink)
+
+
+def test_prefetch_loader_wait_span_per_batch():
+    from repro.core.loader import PrefetchLoader
+
+    tel = Telemetry()
+    sink = tel.attach(MemorySink())
+    batches = [{"x": np.arange(4) + i} for i in range(3)]
+    got = list(PrefetchLoader(batches, telemetry=tel))
+    assert len(got) == 3
+    tel.flush()
+    names = [r["name"] for r in sink.records]
+    # One wait per batch plus the one that meets the end of the stream.
+    assert names.count("loader/wait") == 4
+    assert names.count("loader/stage") == 3
+    assert tel.histogram("loader/prefetch_wait") is None
+    assert all(r["dur_s"] >= 0 for r in sink.records
+               if r["name"] == "loader/wait")
+
+
+def test_pipeline_hooks_share_its_telemetry_in_train_and_eval():
+    from repro.core.tg_hooks import DeviceRecencyNeighborHook
+    from repro.data import generate
+    from repro.train.loop import CTDGLinkPipeline
+
+    tel = Telemetry()
+    sink = tel.attach(MemorySink())
+    pipe = CTDGLinkPipeline("tgat", generate("wikipedia", scale=0.003, seed=0),
+                            batch_size=100, seed=0, device_sampling=True,
+                            telemetry=tel)
+    assert pipe.manager.telemetry is tel
+    hook = "hooks/" + next(h.name for h in pipe.manager.hooks()
+                           if isinstance(h, DeviceRecencyNeighborHook))
+    pipe.train_epoch()
+    train = [r["name"] for r in sink.drain()]
+    pipe.evaluate("val")
+    evals = [r["name"] for r in sink.records]
+    n_train = -(-len(pipe.train_data.src) // 100)
+    assert train.count("hooks") == train.count(hook) == n_train
+    assert train.count("ctdg/dispatch") == n_train
+    assert train.count("loader/stage") == n_train
+    assert train.count("loader/wait") == n_train + 1
+    assert "ctdg/step" not in train
+    assert evals.count("ctdg/eval_dispatch") == -(-len(pipe.val_data.src)
+                                                  // 100)
+    assert evals.count(hook) > evals.count("ctdg/eval_dispatch")  # + warm
+
+
+def test_tgn_second_epoch_compiles_nothing():
+    """A new epoch's fresh memory is placed as trained memory is, so its
+    first step reuses the compiled step (placed apart, it compiled a
+    second one)."""
+    from repro.data import generate
+    from repro.train.loop import CTDGLinkPipeline
+
+    # Fixed-width batches: no node-event tensors of varying length.
+    data = generate("wikipedia", scale=0.003, seed=0)
+    tel = Telemetry()
+    sink = tel.attach(MemorySink())
+    pipe = CTDGLinkPipeline("tgn", data, batch_size=64, seed=0,
+                            device_sampling=True, telemetry=tel)
+    pipe.train_epoch()
+    assert any(r["name"] == "jax/compile"
+               and r["attrs"]["fun"] == "jit(train_step)"
+               for r in sink.records)
+    tel.reset()
+    sink.drain()
+    pipe.train_epoch()
+    assert tel.counter_value("jax/compiles") == 0
+    assert not [r for r in sink.records if r["name"] == "jax/compile"]
 
 
 # --------------------------------------------------- train-history parity
@@ -240,7 +460,7 @@ def test_single_sink_observes_train_serve_and_storage(tmp_path):
     TrainLoop(pipe).fit(epochs=1)
     assert any(r["kind"] == "span" and r["name"] == "ctdg/epoch"
                for r in mem.records)
-    assert any(r["kind"] == "span" and r["name"] == "ctdg/step"
+    assert any(r["kind"] == "span" and r["name"] == "ctdg/dispatch"
                for r in mem.records)
 
     # -- one serving chaos burst ----------------------------------------
