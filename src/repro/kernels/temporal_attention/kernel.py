@@ -5,29 +5,21 @@ epoch time. On TPU the hot loop is: for each seed node, attend its K most
 recent neighbors (K = 10..32, padded). See ``docs/kernels.md`` for the full
 memory-space layout and parity-testing story.
 
-Layout. Every kernel works on 2-D, head-flattened rows: a seed's query is
-one ``(1, H*D)`` row and its neighborhood one ``(K, H*D)`` slab, with K on
-sublanes and the heads side by side on lanes. The wrappers reshape the
-public ``(S, H, D)`` / ``(N, H, D)`` arrays to ``(S, H*D)`` / ``(N, H*D)``;
-the fused kernels also pad H*D to a multiple of 128 lanes (zeros no head
-reads), because a single-row DMA must move whole lane tiles. Attention
-runs on the VPU:
-per head, the scores are a lane reduction of ``k * (q * head_mask)`` and
-the output a sublane reduction of ``p * v`` — exact f32 and free of the
-batched contractions without a free lhs dimension (``"hd,khd->hk"``) that
-Mosaic cannot lower. The only MXU work is the plain 2-D bias matmuls,
-pinned to ``Precision.HIGHEST``.
+Layout. Every kernel works on 2-D, head-flattened rows and computes a
+*block* of B seeds as one unit: the block's B*K neighbor rows form one
+``(B*K, H*D)`` slab, with the B*K (seed, slot) pairs on sublanes and the
+heads side by side on lanes. A ``(B*K, B)`` one-hot seed matrix maps
+between per-seed and per-slot rows: the queries are spread over their
+slots by one matmul, the masked softmax reduces over each seed's K rows
+with it on the VPU, and the output sums the weighted values back per seed
+with one transposed matmul. Per head, the scores are a lane reduction of
+``k * (q * head_mask)``. Every MXU matmul is 2-D, at
+``Precision.HIGHEST`` with f32 accumulation; the kernel body is the same
+size whatever B is (no Python loop over seeds or slots).
 
 ``temporal_attention_kernel`` is the un-fused baseline: it consumes
-pre-gathered ``(S, K, H, D)`` k/v tensors, tiles seeds into VMEM blocks and
-walks the block's seeds with the same per-seed attention as the fused path.
-
-Grid: (num_seed_blocks,) — embarrassingly parallel over seeds.
-Blocks (VMEM):
-  q:    (block_s, H*D)
-  k/v:  (block_s, K, H*D)   — gathered neighbor features
-  mask: (block_s, K, 1)
-  o:    (block_s, H*D)
+pre-gathered k/v, flattened in the wrapper to ``(S*K, H*D)`` rows, and
+attends a grid step's seeds as one block.
 
 ``fused_temporal_layer_kernel`` is the device-sampling variant (the layer-1
 compute of TGAT/TGN when ``device_sampling=True``): instead of consuming
@@ -42,32 +34,36 @@ additive biases computed in VMEM:
           + phi(t_s - t_j) @ Wt_k               # in-kernel time bias
           + edge_feats[eid_j] @ We_k            # DMA'd edge bias
 
-so the fat ``(S, K, H, D)`` intermediates never exist in HBM. XLA gathers
-each seed's packed buffer row (ids, times, eids: an ``(S, K, 3)`` int32
-index block) up front; its clamped ids reach SMEM as DMA source indices
-and its validity / time-delta columns reach VMEM, blocked with the seeds.
-Each neighbor's table/edge-feature row is then DMA'd from HBM into VMEM
-scratch. Seeds may be negative (hop-2 frontier padding): the DMA index is
-clamped and the whole row masked out, so the 2-hop TGAT frontier can run
-through the kernel unclamped.
+so the fat ``(S, K, H, D)`` intermediates never exist in HBM. The k and v
+tables travel as one ``(N, 1, 2*H*D)`` row table (one DMA per neighbor),
+and the key and value slices of each bias projection as one
+``(d, 2*H*D)`` matrix (one matmul per block). XLA gathers each seed's
+packed buffer row up front (ids, times, eids: an ``(S, K, 3)`` int32 index
+block); its ids reach SMEM as DMA source indices, -1 where a slot is
+empty or its edge featureless (nothing is copied there), and its validity
+/ time-delta columns reach VMEM as one ``(3, B*K)`` group per block. Seeds
+may be negative (hop-2 frontier padding): their slots copy nothing and
+their rows are masked out, so the 2-hop TGAT frontier can run through the
+kernel unclamped.
 
-Per-seed DMAs are double-buffered: while seed ``j``'s neighborhood is being
-reduced on the VPU/MXU, seed ``j+1``'s K neighbor-row copies (issued
-back-to-back, all in flight at once) land in the other half of a 2-slot
-scratch. ``fused_recency_attention_kernel`` (ids-only buffer, no bias
-folding) is kept as a thin wrapper and runs through
-the same double-buffered body.
+Grid. A grid step holds a tile of ``nblk`` blocks of B seeds and walks
+them through a 2-slot DMA pipeline: while block i is computed, block
+i+1's neighbor-row copies (one per valid slot, issued from a rolled loop,
+all in flight at once) land in the other slot. B comes from the VMEM the staging may
+take (``_plan``): each staged row is a ``(1, width)`` tile that pads to 8
+sublanes. ``fused_recency_attention_kernel`` (ids-only buffer, no bias
+folding) is a thin wrapper over the same body.
 
 ``fused_temporal_layer_bwd_kernel`` is the flash-attention-style backward:
-it re-stages every seed's neighborhood through the same double-buffered DMA
-pipeline, recomputes the attention weights in VMEM, and produces all input
-gradients without ever materializing an (S, K, ·) tensor in HBM — dq as a
-blocked output, dk_table/dv_table by sequential per-row DMA
-read-modify-write into ANY-space outputs aliased to zero-initialized
-operands (the TPU has no atomics; the grid is sequential, so the
-read-modify-write is race-free and handles duplicate neighbor ids exactly),
-and the small weight gradients (time/edge projections, Bochner parameters)
-as VMEM-resident accumulators that live across the whole grid.
+it re-stages every block through the same pipeline, recomputes the
+attention weights in VMEM, and produces all input gradients without ever
+materializing an (S, K, ·) tensor in HBM — dq as a blocked output, the
+weight gradients (time/edge projections, Bochner parameters) as
+VMEM-resident accumulators with one ``(·)ᵀ @ (·)`` matmul per block, and
+dk/dv by per-row DMA read-modify-write into an ANY-space output aliased
+to a zero-initialized operand (the TPU has no atomics; the grid is
+sequential and each read-modify-write ends before the next begins, so
+duplicate neighbor ids accumulate exactly).
 
 The jnp oracles in ``ref.py`` remain the correctness references
 (``interpret=True`` executes these kernel bodies on CPU for parity tests).
@@ -83,8 +79,24 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs import telemetry
+
 NEG_INF = -1e30
 _HI = jax.lax.Precision.HIGHEST
+_LANE = 128
+_SUBLANE = 8
+# VMEM for the 2-slot neighbor-row staging, both slots. v5e's default
+# scoped VMEM is 16 MiB: 11 MiB land the rows, the rest holds the
+# pipelined operand blocks, the backward's accumulators and the block's
+# f32 intermediates (the forward and backward compile for a v5e at TGAT's
+# and TGN's widths, ``tests/test_tpu_compile.py``).
+_STAGE_BYTES = 11 << 20
+# A staged (1, width) row pads to a whole (8, 128) f32 tile per 128 lanes
+# (a (16, 128) bf16 one): 4 KiB either way.
+_TILE_BYTES = 4096
+# Seeds a grid step walks through the pipeline; only its first block's
+# copies are not hidden behind compute.
+_TILE_SEEDS = 128
 
 
 def _mm(a, b, contract=((1,), (0,))):
@@ -103,46 +115,74 @@ def _head_masks(heads: int, hdim: int, width: int):
             for h in range(heads)]
 
 
-def _masked_softmax(s, valid):
-    """Softmax over the K sublanes of an (K, 1) score column, with invalid
-    slots excluded and a fully-invalid column zeroed — identical to the
-    oracle's ``softmax`` + ``where(mask.any(), ·, 0)``."""
+def _seed_onehot(bs: int, kbuf: int):
+    """(B*K, B) f32: row ``r`` of a block belongs to its seed ``r // K``."""
+    shape = (bs * kbuf, bs)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    first = jax.lax.broadcasted_iota(jnp.int32, shape, 1) * kbuf
+    return ((row >= first) & (row < first + kbuf)).astype(jnp.float32)
+
+
+def _per_seed(seg, x):
+    """(B*K, 1) per-slot column -> (1, B) sums over each seed's slots."""
+    return jnp.sum(seg * x, axis=0, keepdims=True)
+
+
+def _per_slot(seg, y):
+    """(1, B) per-seed row -> (B*K, 1): each slot gets its seed's value."""
+    return jnp.sum(seg * y, axis=1, keepdims=True)
+
+
+def _segment_softmax(s, valid, seg):
+    """Softmax of a (B*K, 1) score column within each seed's K slots, with
+    invalid slots excluded and a seed with none valid zeroed — identical
+    to the oracle's ``softmax`` + ``where(mask.any(), ·, 0)``."""
     s = jnp.where(valid > 0, s, NEG_INF)
-    p = jnp.exp(s - s.max(axis=0, keepdims=True))
-    p = p / jnp.maximum(p.sum(axis=0, keepdims=True), 1e-30)
-    return p * valid.max(axis=0, keepdims=True)
+    top = jnp.max(jnp.where(seg > 0, s, NEG_INF), axis=0, keepdims=True)
+    p = jnp.exp(s - _per_slot(seg, top))
+    p = p / jnp.maximum(_per_slot(seg, _per_seed(seg, p)), 1e-30)
+    return p * valid
 
 
-def _attend(q, k, v, valid, masks):
-    """One seed's multi-head attention on the VPU.
-
-    q: (1, H*D) scaled query; k, v: (K, H*D); valid: (K, 1) f32 in {0, 1};
-    masks: ``_head_masks``. Returns the (1, H*D) head-concatenated output.
-    """
-    out = jnp.zeros(q.shape, jnp.float32)
-    for m in masks:
-        p = _masked_softmax(jnp.sum(k * (q * m), axis=1, keepdims=True),
-                            valid)
-        out = out + jnp.sum(p * v, axis=0, keepdims=True) * m
+def _spread(cols, masks):
+    """Per-head (B*K, 1) columns -> one (B*K, width) array, each head's
+    column on that head's lanes."""
+    out = cols[0] * masks[0]
+    for c, m in zip(cols[1:], masks[1:]):
+        out = out + c * m
     return out
 
 
+def _attention_probs(k, q_rows, valid, seg, masks):
+    """Per head, the (B*K, 1) attention weights of a block; ``q_rows`` is
+    the scaled query spread over its seed's slots."""
+    return [_segment_softmax(jnp.sum(k * (q_rows * m), axis=1, keepdims=True),
+                             valid, seg) for m in masks]
+
+
+def _attend_block(q, k, v, valid, seg, masks):
+    """A block's multi-head attention: q (B, width) scaled queries; k, v
+    (B*K, width); valid (B*K, 1) f32 in {0, 1}. Returns (B, width)."""
+    p = _attention_probs(k, _mm(seg, q), valid, seg, masks)
+    return _mm(seg, _spread(p, masks) * v, ((0,), (0,)))
+
+
+def _rows(i, n: int):
+    """The ``i``-th run of ``n`` rows (``n`` sublane-aligned on the chip)."""
+    return pl.ds(pl.multiple_of(i * n, n), n)
+
+
 def _temporal_attention_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *,
-                               scale: float, block_s: int, heads: int,
-                               hdim: int):
-    masks = _head_masks(heads, hdim, heads * hdim)
-
-    def per_seed(j, carry):
-        q = q_ref[pl.ds(j, 1), :].astype(jnp.float32) * scale   # (1, H*D)
-        o = _attend(q, k_ref[j].astype(jnp.float32),
-                    v_ref[j].astype(jnp.float32), mask_ref[j], masks)
-        o_ref[pl.ds(j, 1), :] = o.astype(o_ref.dtype)
-        return carry
-
-    jax.lax.fori_loop(0, block_s, per_seed, 0)
+                               scale: float, block_s: int, kbuf: int,
+                               heads: int, hdim: int):
+    o_ref[...] = _attend_block(
+        q_ref[...].astype(jnp.float32) * scale,
+        k_ref[...].astype(jnp.float32), v_ref[...].astype(jnp.float32),
+        mask_ref[...], _seed_onehot(block_s, kbuf),
+        _head_masks(heads, hdim, heads * hdim)).astype(o_ref.dtype)
 
 
-def temporal_attention_kernel(q, k, v, mask, *, block_s: int = 128,
+def temporal_attention_kernel(q, k, v, mask, *, block_s: int = 32,
                               scale: float | None = None,
                               interpret: bool = False):
     """q: (S, H, D); k, v: (S, K, H, D); mask: (S, K) -> (S, H, D)."""
@@ -153,21 +193,21 @@ def temporal_attention_kernel(q, k, v, mask, *, block_s: int = 128,
     block_s = min(block_s, S)
     pad = (-S) % block_s
     q2 = jnp.pad(q.reshape(S, H * D), ((0, pad), (0, 0)))
-    k2 = jnp.pad(k.reshape(S, K, H * D), ((0, pad), (0, 0), (0, 0)))
-    v2 = jnp.pad(v.reshape(S, K, H * D), ((0, pad), (0, 0), (0, 0)))
-    m2 = jnp.pad(mask.astype(jnp.float32)[..., None],
-                 ((0, pad), (0, 0), (0, 0)))
+    k2, v2 = (jnp.pad(x.reshape(S * K, H * D), ((0, pad * K), (0, 0)))
+              for x in (k, v))
+    m2 = jnp.pad(mask.astype(jnp.float32).reshape(S * K, 1),
+                 ((0, pad * K), (0, 0)))
     ns = (S + pad) // block_s
+    rows = pl.BlockSpec((block_s * K, H * D), lambda i: (i, 0))
 
     out = pl.pallas_call(
         functools.partial(_temporal_attention_kernel, scale=scale,
-                          block_s=block_s, heads=H, hdim=D),
+                          block_s=block_s, kbuf=K, heads=H, hdim=D),
         grid=(ns,),
         in_specs=[
             pl.BlockSpec((block_s, H * D), lambda i: (i, 0)),
-            pl.BlockSpec((block_s, K, H * D), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_s, K, H * D), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_s, K, 1), lambda i: (i, 0, 0)),
+            rows, rows,
+            pl.BlockSpec((block_s * K, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_s, H * D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S + pad, H * D), q.dtype),
@@ -177,9 +217,6 @@ def temporal_attention_kernel(q, k, v, mask, *, block_s: int = 128,
         interpret=interpret,
     )(q2, k2, v2, m2)
     return out[:S].reshape(S, H, D)
-
-
-_LANE = 128
 
 
 def _round_up(n: int, m: int = _LANE) -> int:
@@ -198,22 +235,59 @@ def _row_table(x, width: int):
     return _pad2(x, x.shape[0], width).reshape(x.shape[0], 1, width)
 
 
+def _kv_pair(a, b, rows: int, width: int):
+    """Two (R, d) arrays -> one (rows, 2*width) f32 array, ``a`` on the
+    first ``width`` lanes and ``b`` on the next, each zero-padded."""
+    return jnp.concatenate([_pad2(x.astype(jnp.float32), rows, width)
+                            for x in (a, b)], axis=1)
+
+
+def _plan(S: int, K: int, width: int, block_s, name: str) -> dict:
+    """Seeds per block B, blocks per grid step and seed padding of one call.
+
+    B is ``block_s`` when given (tests), else as many seeds as the staging
+    budget holds: 2 slots x K rows x ``width`` lanes, each row a padded
+    tile — rounded down to a multiple of 8, at least 8. B never exceeds S
+    rounded up to 8, so small calls do not pad to a large block. A grid
+    step then takes up to ``_TILE_SEEDS`` seeds in whole blocks, spread so
+    the padding stays under one block per step. Records the choice in
+    every enabled ``repro.obs`` telemetry (this runs as the wrapper traces,
+    once per call shape).
+    """
+    if block_s is None:
+        per_seed = 2 * K * (width // _LANE) * _TILE_BYTES
+        block_s = max(_SUBLANE,
+                      _STAGE_BYTES // per_seed // _SUBLANE * _SUBLANE)
+    bs = min(block_s, _round_up(S, _SUBLANE))
+    blocks = -(-S // bs)
+    steps = -(-blocks // max(1, _TILE_SEEDS // bs))
+    nblk = -(-blocks // steps)
+    pad = steps * nblk * bs - S
+    telemetry.note(name, seeds=S, slots=K, block=bs, tile=nblk * bs,
+                   pad_share=pad / (S + pad))
+    return dict(block_s=bs, nblk=nblk, ns=steps, pad=pad)
+
+
 def _layer_inputs(q, k_table, v_table, seeds, seed_times, buf, time_w,
-                  time_b, wt_k, wt_v, edge_feats, we_k, we_v, block_s):
+                  time_b, wt_k, wt_v, edge_feats, we_k, we_v, block_s,
+                  name):
     """Assemble the fused forward/backward pallas_call inputs.
 
     XLA gathers each seed's packed buffer row up front — an (S, K, 3)
     int32 index block, not the fat (S, K, H, D) features — and splits it
-    into the DMA indices (``idx``: (S, 2K) clamped neighbor and edge ids,
-    blocked into SMEM) and the vector columns (``cols``: (S, 3, K) f32 slot
+    into the DMA indices (``idx``: (S, 2K) neighbor and edge ids, -1 for
+    an invalid slot or a featureless edge, blocked into SMEM) and the
+    vector columns (``cols``: one (3, B*K) f32 group per block of slot
     validity, query/neighbor time delta and edge validity, blocked into
-    VMEM). The node tables and the edge-feature storage become
-    ``_row_table``s in ANY/HBM, lane-padded to multiples of 128; q and
-    the weight groups are lane-padded to the same H*D width with zeros,
-    which the attention never reads (the head masks cover the real lanes).
+    VMEM). The node tables become one ``_row_table`` of k | v rows in
+    ANY/HBM, each half lane-padded to a multiple of 128, and the
+    edge-feature storage another; q is lane-padded to the same H*D width
+    and the key | value slices of each weight group are paired the same
+    way, with zeros the attention never reads (the head masks cover the
+    real lanes).
 
     Returns ``(lead, rest, meta)``: the blocked seed-axis operands
-    ``[idx, cols, q]`` and the grid-invariant ``[k, v, time group, edge
+    ``[idx, cols, q]`` and the grid-invariant ``[kv, time group, edge
     group]``, each a list of ``(operand, BlockSpec)``, and the static
     sizes the wrappers need.
     """
@@ -221,8 +295,10 @@ def _layer_inputs(q, k_table, v_table, seeds, seed_times, buf, time_w,
     HD = H * D
     HDp = _round_up(HD)
     K = buf.shape[1]
-    block_s = min(block_s, S)
-    pad = (-S) % block_s
+    DEp = 0 if we_k is None else _round_up(edge_feats.shape[1])
+    m = _plan(S, K, 2 * HDp + DEp, block_s, name)
+    bs, pad = m["block_s"], m["pad"]
+    tile = m["nblk"] * bs
 
     seeds = seeds.astype(jnp.int32)
     seed_times = (jnp.zeros_like(seeds) if seed_times is None
@@ -230,199 +306,222 @@ def _layer_inputs(q, k_table, v_table, seeds, seed_times, buf, time_w,
     rows = buf.astype(jnp.int32)[jnp.maximum(seeds, 0)]         # (S, K, 3)
     ids, eids = rows[..., 0], rows[..., 2]
     valid = (ids >= 0) & (seeds >= 0)[:, None]  # seed < 0: hop-2 padding
+    has_edge = valid & (eids >= 0)
     dt = seed_times[:, None] - rows[..., 1]   # int32 first, as time_encode
-    cols = jnp.stack([valid, dt, eids >= 0], axis=1).astype(jnp.float32)
-    idx = jnp.concatenate([jnp.maximum(ids, 0), jnp.maximum(eids, 0)], 1)
+    # One (3, B*K) row group per block, each column padded and cut into
+    # blocks before stacking: no (S, K, ·) float array in HBM, and no
+    # 3-lane array padded to 128 lanes.
+    cols = jnp.stack([jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0)))
+                      .reshape(-1, bs * K) for x in (valid, dt, has_edge)],
+                     axis=1)
+    # DMA source rows; -1 where there is nothing to copy or scatter.
+    idx = jnp.concatenate([jnp.where(valid, ids, -1),
+                           jnp.where(has_edge, eids, -1)], 1)
 
-    blocked = lambda shp: pl.BlockSpec(  # noqa: E731
-        shp, lambda i: (i,) + (0,) * (len(shp) - 1))
+    blocked = lambda shp: pl.BlockSpec(shp, lambda i: (i, 0))  # noqa: E731
     full = lambda a: pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)  # noqa: E731
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     lead = [
-        (jnp.pad(idx, ((0, pad), (0, 0))),
-         pl.BlockSpec((block_s, 2 * K), lambda i: (i, 0),
+        (jnp.pad(idx, ((0, pad), (0, 0)), constant_values=-1),
+         pl.BlockSpec((tile, 2 * K), lambda i: (i, 0),
                       memory_space=pltpu.SMEM)),
-        (jnp.pad(cols, ((0, pad), (0, 0), (0, 0))), blocked((block_s, 3, K))),
-        (_pad2(q.reshape(S, HD), S + pad, HDp), blocked((block_s, HDp))),
+        (cols, pl.BlockSpec((m["nblk"], 3, bs * K), lambda i: (i, 0, 0))),
+        (_pad2(q.reshape(S, HD), S + pad, HDp), blocked((tile, HDp))),
     ]
-    rest = [(_row_table(t.reshape(t.shape[0], HD), HDp), hbm)
-            for t in (k_table, v_table)]
+    N = k_table.shape[0]
+    kv = _kv_pair(k_table.reshape(N, HD), v_table.reshape(N, HD), N, HDp)
+    rest = [(kv.reshape(N, 1, 2 * HDp), hbm)]
     if wt_k is not None:
         d_time = wt_k.shape[0]
         tw = time_w.reshape(1, -1).astype(jnp.float32)
         tb = time_b.reshape(1, -1).astype(jnp.float32)
-        wtk, wtv = (_pad2(w.reshape(d_time, HD).astype(jnp.float32),
-                          d_time, HDp) for w in (wt_k, wt_v))
-        rest += [(a, full(a)) for a in (tw, tb, wtk, wtv)]
-    DEp = 0
+        wt = _kv_pair(wt_k.reshape(d_time, HD), wt_v.reshape(d_time, HD),
+                      d_time, HDp)
+        rest += [(a, full(a)) for a in (tw, tb, wt)]
     if we_k is not None:
         d_edge = edge_feats.shape[1]
-        DEp = _round_up(d_edge)
-        wek, wev = (_pad2(w.reshape(d_edge, HD).astype(jnp.float32),
-                          DEp, HDp) for w in (we_k, we_v))
-        rest += [(_row_table(edge_feats, DEp), hbm), (wek, full(wek)),
-                 (wev, full(wev))]
-    meta = dict(S=S, pad=pad, K=K, H=H, D=D, HDp=HDp, DEp=DEp,
-                block_s=block_s, ns=(S + pad) // block_s,
-                kv_dtype=k_table.dtype,
+        we = _kv_pair(we_k.reshape(d_edge, HD), we_v.reshape(d_edge, HD),
+                      DEp, HDp)
+        rest += [(_row_table(edge_feats, DEp), hbm), (we, full(we))]
+    meta = dict(m, S=S, K=K, H=H, D=D, HDp=HDp, DEp=DEp, tile=tile,
                 e_dtype=None if we_k is None else edge_feats.dtype)
     return lead, rest, meta
 
 
 def _staging_scratch(meta, has_edge):
-    """2-slot VMEM landing zones for the neighbor rows, and their per-slot
-    DMA semaphores (k, v[, edge]). Each row lands in its own (1, width)
-    tile, so a single-row DMA never slices a sublane tile (wider than 128
-    lanes, a (K, width) slab would be tiled (8, 128) and refuse it)."""
-    K, HDp = meta["K"], meta["HDp"]
-    scratch = [pltpu.VMEM((2, K, 1, HDp), meta["kv_dtype"]),
-               pltpu.VMEM((2, K, 1, HDp), meta["kv_dtype"])]
+    """2-slot VMEM landing zones for a block's neighbor rows (k | v, and
+    edge features), and their per-slot DMA semaphores. Each row lands in
+    its own (1, width) tile, so a single-row DMA never slices a sublane
+    tile (a (B*K, width) slab would be tiled (8, 128) and refuse it). An
+    SMEM array counts the copies each slot's block issued, per table."""
+    rows = meta["block_s"] * meta["K"]
+    scratch = [pltpu.VMEM((2, rows, 1, 2 * meta["HDp"]), jnp.float32)]
     if has_edge:
-        scratch.append(pltpu.VMEM((2, K, 1, meta["DEp"]), meta["e_dtype"]))
-    return scratch, [pltpu.SemaphoreType.DMA((2,))] * (3 if has_edge else 2)
+        scratch.append(pltpu.VMEM((2, rows, 1, meta["DEp"]), meta["e_dtype"]))
+    return scratch, [pltpu.SemaphoreType.DMA((2,))] * len(scratch) + [
+        pltpu.SMEM((2, len(scratch)), jnp.int32)]   # copies issued per slot
 
 
 def _unpack_tables(it, has_time: bool, has_edge: bool) -> dict:
     """Pop the grid-invariant refs in ``_layer_inputs``' ``rest`` order."""
-    t = {"k": next(it), "v": next(it)}      # (N, 1, H*D) ANY node tables
+    t = {"kv": next(it)}                    # (N, 1, 2*H*D) ANY k | v rows
     if has_time:
         t.update(tw=next(it), tb=next(it),  # (1, d_time) Bochner params
-                 wtk=next(it), wtv=next(it))  # (d_time, H*D) time proj
+                 wt=next(it))               # (d_time, 2*H*D) time proj
     if has_edge:
         t.update(ef=next(it),               # (E, 1, d_edge) ANY features
-                 wek=next(it), wev=next(it))  # (d_edge, H*D) edge proj
+                 we=next(it))               # (d_edge, 2*H*D) edge proj
     return t
 
 
-def _make_stager(idx_ref, t, k_scr, v_scr, e_scr, sems, *, kbuf: int,
-                 has_edge: bool):
-    """Build the double-buffered per-seed DMA staging closures.
+def _for_slots(i, bs: int, kbuf: int, body, carry=0):
+    """Run ``body(j, r, kk, carry)`` over block ``i``'s B*K (seed, slot)
+    pairs in two rolled loops: ``j`` the seed's row in the tile, ``r`` the
+    pair's row in the block, ``kk`` the slot. Returns the final carry."""
+    def seed(b, c):
+        return jax.lax.fori_loop(
+            0, kbuf, lambda kk, c: body(i * bs + b, b * kbuf + kk, kk, c), c)
 
-    Shared by the forward and backward fused-layer kernel bodies: both walk
-    the same seed blocks and need the same staged data (the K neighbor k/v
-    table rows, and optionally the K edge-feature rows) in 2-slot scratch.
-    ``idx_ref`` holds the block's clamped neighbor ids (columns ``[0, K)``)
-    and edge ids (``[K, 2K)``) in SMEM, so every source index is known
-    before any copy starts; padding slots copy row 0 and are masked out by
-    the caller.
+    return jax.lax.fori_loop(0, bs, seed, carry)
 
-    Returns ``(stage, wait)``: ``stage(j)`` issues seed j's K row copies
-    back-to-back into slot ``j % 2``, all in flight at once; ``wait(j)``
-    blocks until they have all landed.
+
+def _make_stager(idx_ref, t, kv_scr, e_scr, sems, counts, *, bs: int,
+                 kbuf: int, has_edge: bool):
+    """Build the double-buffered per-block DMA staging closures.
+
+    Shared by the forward and backward kernel bodies: both walk the same
+    blocks and need the same staged rows (the B*K neighbor k | v rows, and
+    optionally the B*K edge-feature rows) in 2-slot scratch. ``idx_ref``
+    holds the tile's neighbor ids (columns ``[0, K)``) and edge ids
+    (``[K, 2K)``) in SMEM, so every source index is known before any copy
+    starts. A slot whose id is -1 (an empty or padding slot, a featureless
+    edge) copies nothing; the caller zeroes what its stale row holds.
+
+    Returns ``(stage, wait)``: ``stage(i)`` issues block i's row copies
+    into slot ``i % 2`` from a rolled loop, all in flight at once, and
+    counts them in ``counts``; ``wait(i)`` blocks until they have all
+    landed, one wait per copy.
     """
+    tables = [(t["kv"], kv_scr, 0)]
+    if has_edge:
+        tables.append((t["ef"], e_scr, kbuf))
 
-    def copies(j, kk):
-        sl = j % 2
-        cps = [
-            pltpu.make_async_copy(t["k"].at[idx_ref[j, kk]],
-                                  k_scr.at[sl, kk], sems[0].at[sl]),
-            pltpu.make_async_copy(t["v"].at[idx_ref[j, kk]],
-                                  v_scr.at[sl, kk], sems[1].at[sl]),
-        ]
-        if has_edge:
-            cps.append(pltpu.make_async_copy(
-                t["ef"].at[idx_ref[j, kbuf + kk]], e_scr.at[sl, kk],
-                sems[2].at[sl]))
-        return cps
+    def stage(i):
+        sl = i % 2
 
-    def for_each_copy(j, action):
-        def one(kk, c):
-            for cp in copies(j, kk):
-                action(cp)
-            return c
+        def start(j, r, kk, issued):
+            out = []
+            for (src, scr, col), sem, n in zip(tables, sems, issued):
+                row = idx_ref[j, col + kk]
 
-        jax.lax.fori_loop(0, kbuf, one, 0)
+                @pl.when(row >= 0)
+                def _():
+                    pltpu.make_async_copy(src.at[row], scr.at[sl, r],
+                                          sem.at[sl]).start()
 
-    def stage(j):
-        for_each_copy(j, lambda cp: cp.start())
+                out.append(n + jnp.where(row >= 0, 1, 0))
+            return tuple(out)
 
-    def wait(j):
-        for_each_copy(j, lambda cp: cp.wait())
+        issued = _for_slots(i, bs, kbuf, start, (jnp.int32(0),) * len(tables))
+        for tab, n in enumerate(issued):
+            counts[sl, tab] = n
+
+    def wait(i):
+        sl = i % 2
+        for tab, ((src, scr, _), sem) in enumerate(zip(tables, sems)):
+            one = pltpu.make_async_copy(src.at[0], scr.at[sl, 0], sem.at[sl])
+
+            def step(_, c, one=one):
+                one.wait()
+                return c
+
+            jax.lax.fori_loop(0, counts[sl, tab], step, 0)
 
     return stage, wait
 
 
-def _seed_kv(j, cols_ref, t, k_scr, v_scr, e_scr, *, has_time: bool,
-             has_edge: bool):
-    """Rebuild seed j's biased (K, H*D) keys/values from staged scratch.
+def _block_kv(i, cols_ref, t, kv_scr, e_scr, *, rows: int, has_time: bool,
+              has_edge: bool):
+    """Rebuild block i's biased (B*K, 2*H*D) k | v rows from staged scratch.
 
     Shared by the forward (to attend) and the backward (to recompute the
-    attention weights flash-style). Returns ``(valid, k, v, phi, theta,
-    dt, e)``: the (K, 1) slot validity, the keys/values, the Bochner
-    encoding ``phi = cos(theta)`` of the (K, 1) query/neighbor time deltas
-    ``dt``, and the zeroed edge-feature rows ``e`` (the backward reuses the
-    last four for the weight gradients).
+    attention weights flash-style). Returns ``(valid, kv, phi, theta, dt,
+    e)``: the (B*K, 1) slot validity, the keys | values, the Bochner
+    encoding ``phi = cos(theta)`` of the (B*K, 1) query/neighbor time
+    deltas ``dt``, and the zeroed edge-feature rows ``e`` (the backward
+    reuses the last four for the weight gradients).
     """
-    sl = j % 2
-    c = cols_ref[j].T                   # (K, 3): valid, dt, edge valid
+    sl = i % 2
+    c = cols_ref[i].T                   # (B*K, 3): valid, dt, edge valid
     valid = c[:, 0:1]
-    slab = lambda scr: scr[sl].reshape(scr.shape[1], scr.shape[3]  # noqa: E731
+    slab = lambda scr: scr[sl].reshape(rows, scr.shape[3]  # noqa: E731
                                        ).astype(jnp.float32)
-    k = slab(k_scr)
-    v = slab(v_scr)
+    kv = jnp.where(valid > 0, slab(kv_scr), 0.0)  # uncopied rows are stale
     phi = theta = dt = e = None
     if has_time:
-        # The Bochner encoding phi = cos(dt * w + b) on the VPU, then the
-        # (K, d_time) @ (d_time, H*D) bias matmul on the MXU.
+        # The Bochner encoding phi = cos(dt * w + b) on the VPU, then one
+        # (B*K, d_time) @ (d_time, 2*H*D) bias matmul on the MXU.
         dt = c[:, 1:2]
         theta = dt * t["tw"][...] + t["tb"][...]
         phi = jnp.cos(theta)
-        k = k + _mm(phi, t["wtk"][...])
-        v = v + _mm(phi, t["wtv"][...])
+        kv = kv + _mm(phi, t["wt"][...])
     if has_edge:
-        e = slab(e_scr) * c[:, 2:3]     # zero featureless slots
-        k = k + _mm(e, t["wek"][...])
-        v = v + _mm(e, t["wev"][...])
-    return valid, k, v, phi, theta, dt, e
+        e = jnp.where(c[:, 2:3] > 0, slab(e_scr), 0.0)
+        kv = kv + _mm(e, t["we"][...])
+    return valid, kv, phi, theta, dt, e
 
 
 def _fused_layer_kernel(idx_ref, cols_ref, q_ref, *refs, scale: float,
-                        block_s: int, kbuf: int, heads: int, hdim: int,
-                        has_time: bool, has_edge: bool):
+                        block_s: int, nblk: int, kbuf: int, heads: int,
+                        hdim: int, has_time: bool, has_edge: bool):
     """Double-buffered fused gather + bias-fold + attention body.
 
-    ``idx_ref`` (bs, 2K) SMEM DMA indices, ``cols_ref`` (bs, 3, K) VMEM
-    slot columns and ``q_ref`` (bs, H*D) VMEM queries are this block's
-    seeds; ``refs`` unpacks (in order) the grid-invariant tables and
-    weights (``_unpack_tables``), the (bs, H*D) output and the staging
-    scratch from ``_staging_scratch``.
+    ``idx_ref`` (tile, 2K) SMEM DMA indices, ``cols_ref`` (nblk, 3, B*K)
+    VMEM slot columns and ``q_ref`` (tile, H*D) VMEM queries are this grid
+    step's seeds, ``nblk`` blocks of ``block_s``; ``refs`` unpacks (in
+    order) the grid-invariant tables and weights (``_unpack_tables``), the
+    (tile, H*D) output and the staging scratch from ``_staging_scratch``.
     """
     it = iter(refs)
     t = _unpack_tables(it, has_time, has_edge)
     o_ref = next(it)
-    k_scr, v_scr = next(it), next(it)       # (2, K, 1, H*D) VMEM
-    e_scr = next(it) if has_edge else None  # (2, K, 1, d_edge) VMEM
-    sems = list(it)
+    kv_scr = next(it)                       # (2, B*K, 1, 2*H*D) VMEM
+    e_scr = next(it) if has_edge else None  # (2, B*K, 1, d_edge) VMEM
+    *sems, counts = it                      # staging semaphores, counts
 
-    masks = _head_masks(heads, hdim, q_ref.shape[-1])
-    stage, wait = _make_stager(idx_ref, t, k_scr, v_scr, e_scr, sems,
-                               kbuf=kbuf, has_edge=has_edge)
+    hdp = q_ref.shape[-1]
+    masks = _head_masks(heads, hdim, hdp)
+    seg = _seed_onehot(block_s, kbuf)
+    stage, wait = _make_stager(idx_ref, t, kv_scr, e_scr, sems, counts,
+                               bs=block_s, kbuf=kbuf, has_edge=has_edge)
 
-    # Prologue: stage seed 0; the loop then overlaps seed j+1's copies with
-    # seed j's compute (classic 2-slot software pipeline).
+    # Prologue: stage block 0; the loop then overlaps block i+1's copies
+    # with block i's compute (classic 2-slot software pipeline).
     stage(0)
 
-    def per_seed(j, carry):
-        @pl.when(j + 1 < block_s)
+    def per_block(i, carry):
+        @pl.when(i + 1 < nblk)
         def _():
-            stage(j + 1)
+            stage(i + 1)
 
-        wait(j)
-        valid, k, v, *_ = _seed_kv(j, cols_ref, t, k_scr, v_scr, e_scr,
-                                   has_time=has_time, has_edge=has_edge)
-        q = q_ref[pl.ds(j, 1), :].astype(jnp.float32) * scale
-        o_ref[pl.ds(j, 1), :] = _attend(q, k, v, valid, masks
-                                        ).astype(o_ref.dtype)
+        wait(i)
+        valid, kv, *_ = _block_kv(i, cols_ref, t, kv_scr, e_scr,
+                                  rows=block_s * kbuf, has_time=has_time,
+                                  has_edge=has_edge)
+        seeds = _rows(i, block_s)
+        q = q_ref[seeds, :].astype(jnp.float32) * scale
+        o_ref[seeds, :] = _attend_block(q, kv[:, :hdp], kv[:, hdp:], valid,
+                                        seg, masks).astype(o_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, block_s, per_seed, 0)
+    jax.lax.fori_loop(0, nblk, per_block, 0)
 
 
 def fused_temporal_layer_kernel(
     q, k_table, v_table, seeds, seed_times, buf, *,
     time_w=None, time_b=None, wt_k=None, wt_v=None,
     edge_feats=None, we_k=None, we_v=None,
-    block_s: int = 128, scale: float | None = None,
+    block_s: int | None = None, scale: float | None = None,
     interpret: bool = False,
 ):
     """Fused neighbor-gather + bias-fold + attention over the packed buffer.
@@ -440,26 +539,30 @@ def fused_temporal_layer_kernel(
       edge_feats: (E, d_edge) edge-feature storage (stays in HBM), we_k /
         we_v: (d_edge, H*D) edge-feature slices of the projections.
 
-    Returns (S, H, D). The (S, K, H, D) gathered k/v exist only as 2-slot
-    (K, H*D) VMEM scratch, never in HBM; per-seed DMAs are double-buffered.
+    ``block_s`` overrides the seeds per block (tests); by default it is
+    derived from the widths and the staging budget (``_plan``).
+
+    Returns (S, H, D). The gathered k/v exist only as 2-slot (B*K, 2*H*D)
+    VMEM scratch, never in HBM; per-block DMAs are double-buffered.
     """
     has_time = wt_k is not None
     has_edge = we_k is not None
     lead, rest, m = _layer_inputs(q, k_table, v_table, seeds, seed_times,
                                   buf, time_w, time_b, wt_k, wt_v,
-                                  edge_feats, we_k, we_v, block_s)
-    S, H, D, HDp, bs = m["S"], m["H"], m["D"], m["HDp"], m["block_s"]
+                                  edge_feats, we_k, we_v, block_s,
+                                  "kernel/attention_forward")
+    S, H, D, HDp = m["S"], m["H"], m["D"], m["HDp"]
     scratch, sems = _staging_scratch(m, has_edge)
     operands, in_specs = zip(*(lead + rest))
     out = pl.pallas_call(
         functools.partial(
             _fused_layer_kernel, scale=1.0 / np.sqrt(D) if scale is None
-            else scale, block_s=bs, kbuf=m["K"], heads=H, hdim=D,
-            has_time=has_time, has_edge=has_edge,
+            else scale, block_s=m["block_s"], nblk=m["nblk"], kbuf=m["K"],
+            heads=H, hdim=D, has_time=has_time, has_edge=has_edge,
         ),
         grid=(m["ns"],),
         in_specs=list(in_specs),
-        out_specs=pl.BlockSpec((bs, HDp), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((m["tile"], HDp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S + m["pad"], HDp), q.dtype),
         scratch_shapes=scratch + sems,
         interpret=interpret,
@@ -467,149 +570,138 @@ def fused_temporal_layer_kernel(
     return out[:S, :H * D].reshape(S, H, D)
 
 
+def _scatter_rows(i, idx_ref, dkv_rows, dkv_hbm, cell, sem, *, bs: int,
+                  kbuf: int):
+    """Add block i's dk | dv rows into the aliased table gradient.
+
+    One DMA read-modify-write per valid slot (the TPU has no atomics):
+    read the row, add, write it back, each finished before the next row
+    starts. With the sequential grid, every update reaches HBM exactly,
+    duplicate ids within a block and across blocks included.
+    """
+    def rmw(j, r, kk, c):
+        row = idx_ref[j, kk]
+
+        @pl.when(row >= 0)
+        def _():
+            read = pltpu.make_async_copy(dkv_hbm.at[row], cell, sem)
+            read.start()
+            read.wait()
+            cell[...] = cell[...] + dkv_rows[pl.ds(r, 1), :]
+            write = pltpu.make_async_copy(cell, dkv_hbm.at[row], sem)
+            write.start()
+            write.wait()
+
+        return c
+
+    _for_slots(i, bs, kbuf, rmw)
+
+
 def _fused_layer_bwd_kernel(idx_ref, cols_ref, q_ref, g_ref, *refs,
-                            scale: float, block_s: int, kbuf: int,
+                            scale: float, block_s: int, nblk: int, kbuf: int,
                             heads: int, hdim: int, has_time: bool,
                             has_edge: bool):
     """Flash-style backward body: restage, recompute attention, accumulate.
 
-    Per seed, the neighborhood is re-staged through the same double-buffered
-    DMA pipeline as the forward, the biased k/v and attention weights are
-    recomputed in VMEM, and the chain rule is applied locally per head:
+    Per block, the neighborhoods are re-staged through the same
+    double-buffered DMA pipeline as the forward, the biased k/v and
+    attention weights are recomputed in VMEM, and the chain rule is
+    applied per head on the block's (B*K, ·) rows:
 
       dv   = p ⊗ g              ds = p * (dp - Σ_k p·dp)     dp = g · v
       dq   = (ds · k) * scale   dk = ds ⊗ (q * scale)
 
-    dq writes to a blocked output; dk/dv rows are scattered into the
-    zero-initialized ANY-space dk_table/dv_table outputs by sequential DMA
-    read-modify-write (grid + fori_loop ordering makes duplicate neighbor
-    ids safe without atomics); the weight gradients (time/edge projection
-    slices and Bochner parameters) live in VMEM-resident accumulator outputs
-    initialized at program 0.
+    dq writes to a blocked output; the dk | dv rows are scattered into the
+    zero-initialized ANY-space table gradient by ``_scatter_rows``; the
+    weight gradients (time/edge projection slices and Bochner parameters)
+    live in VMEM-resident accumulator outputs initialized at program 0,
+    each updated by one matmul or reduction per block.
     """
     it = iter(refs)
     t = _unpack_tables(it, has_time, has_edge)
-    next(it)                             # dk zeros operand (aliased → dk_hbm)
-    next(it)                             # dv zeros operand (aliased → dv_hbm)
-    dq_ref = next(it)                    # (bs, H*D) VMEM blocked output
-    dk_hbm = next(it)                    # (N, 1, H*D) f32 ANY output (aliased)
-    dv_hbm = next(it)                    # (N, 1, H*D) f32 ANY output (aliased)
-    dtw_ref = dtb_ref = dwtk_ref = dwtv_ref = None
-    dwek_ref = dwev_ref = None
+    next(it)                             # zeros operand (aliased → dkv_hbm)
+    dq_ref = next(it)                    # (tile, H*D) VMEM blocked output
+    dkv_hbm = next(it)                   # (N, 1, 2*H*D) f32 ANY (aliased)
+    dtw_ref = dtb_ref = dwt_ref = dwe_ref = None
     if has_time:
         dtw_ref = next(it)               # (1, d_time) resident accumulator
         dtb_ref = next(it)
-        dwtk_ref = next(it)              # (d_time, H*D) resident accumulator
-        dwtv_ref = next(it)
+        dwt_ref = next(it)               # (d_time, 2*H*D) resident
     if has_edge:
-        dwek_ref = next(it)              # (d_edge, H*D) resident accumulator
-        dwev_ref = next(it)
-    k_scr, v_scr = next(it), next(it)    # (2, K, 1, H*D) VMEM
+        dwe_ref = next(it)               # (d_edge, 2*H*D) resident
+    kv_scr = next(it)                    # (2, B*K, 1, 2*H*D) VMEM
     e_scr = next(it) if has_edge else None
-    dk_rows = next(it)                   # (K, H*D) f32 — this seed's dk
-    dv_rows = next(it)                   # (K, H*D) f32
-    rk_row = next(it)                    # (1, H*D) f32 read-modify-write cell
-    rv_row = next(it)                    # (1, H*D) f32
-    sem_rk = next(it)                    # DMA — dk row read-modify-write
-    sem_rv = next(it)
-    sems = list(it)                      # staging semaphores
+    sems = [next(it) for _ in range(2 if has_edge else 1)]
+    counts = next(it)                    # SMEM copies issued per slot
+    dkv_rows = next(it)                  # (B*K, 2*H*D) f32 — the block's
+    cell = next(it)                      # (1, 2*H*D) f32 RMW cell
+    sem = next(it)                       # DMA — the RMW's read and write
 
-    masks = _head_masks(heads, hdim, q_ref.shape[-1])
+    hdp = q_ref.shape[-1]
+    masks = _head_masks(heads, hdim, hdp)
+    seg = _seed_onehot(block_s, kbuf)
+    rows = block_s * kbuf
 
     @pl.when(pl.program_id(0) == 0)
     def _():
-        if has_time:
-            dtw_ref[...] = jnp.zeros_like(dtw_ref)
-            dtb_ref[...] = jnp.zeros_like(dtb_ref)
-            dwtk_ref[...] = jnp.zeros_like(dwtk_ref)
-            dwtv_ref[...] = jnp.zeros_like(dwtv_ref)
-        if has_edge:
-            dwek_ref[...] = jnp.zeros_like(dwek_ref)
-            dwev_ref[...] = jnp.zeros_like(dwev_ref)
+        for ref in (dtw_ref, dtb_ref, dwt_ref, dwe_ref):
+            if ref is not None:
+                ref[...] = jnp.zeros_like(ref)
 
-    stage, wait = _make_stager(idx_ref, t, k_scr, v_scr, e_scr, sems,
-                               kbuf=kbuf, has_edge=has_edge)
+    stage, wait = _make_stager(idx_ref, t, kv_scr, e_scr, sems, counts,
+                               bs=block_s, kbuf=kbuf, has_edge=has_edge)
     stage(0)
 
-    def per_seed(j, carry):
-        @pl.when(j + 1 < block_s)
+    def per_block(i, carry):
+        @pl.when(i + 1 < nblk)
         def _():
-            stage(j + 1)
+            stage(i + 1)
 
-        wait(j)
-        valid, k, v, phi, theta, dt, e = _seed_kv(
-            j, cols_ref, t, k_scr, v_scr, e_scr,
-            has_time=has_time, has_edge=has_edge)
-        qs = q_ref[pl.ds(j, 1), :].astype(jnp.float32) * scale   # (1, H*D)
-        g = g_ref[pl.ds(j, 1), :].astype(jnp.float32)            # (1, H*D)
+        wait(i)
+        valid, kv, phi, theta, dt, e = _block_kv(
+            i, cols_ref, t, kv_scr, e_scr, rows=rows, has_time=has_time,
+            has_edge=has_edge)
+        k, v = kv[:, :hdp], kv[:, hdp:]
+        seeds = _rows(i, block_s)
+        q_rows = _mm(seg, q_ref[seeds, :].astype(jnp.float32) * scale)
+        g_rows = _mm(seg, g_ref[seeds, :].astype(jnp.float32))
 
-        dq = jnp.zeros(qs.shape, jnp.float32)
-        dk = jnp.zeros(k.shape, jnp.float32)
-        dv = jnp.zeros(v.shape, jnp.float32)
-        for m in masks:
-            qm, gm = qs * m, g * m
-            p = _masked_softmax(jnp.sum(k * qm, axis=1, keepdims=True),
-                                valid)                           # (K, 1)
-            dp = jnp.sum(v * gm, axis=1, keepdims=True)          # (K, 1)
-            ds = p * (dp - jnp.sum(p * dp, axis=0, keepdims=True))
-            dq = dq + jnp.sum(ds * k, axis=0, keepdims=True) * m
-            dk = dk + ds * qm                                    # ds ⊗ q
-            dv = dv + p * gm                                     # p ⊗ g
-        dq_ref[pl.ds(j, 1), :] = (dq * scale).astype(dq_ref.dtype)
+        probs = _attention_probs(k, q_rows, valid, seg, masks)
+        ds = []
+        for p, m in zip(probs, masks):
+            dp = jnp.sum(v * (g_rows * m), axis=1, keepdims=True)
+            ds.append(p * (dp - _per_slot(seg, _per_seed(seg, p * dp))))
+        ds = _spread(ds, masks)                                  # (B*K, HD)
+        dq_ref[seeds, :] = (_mm(seg, ds * k, ((0,), (0,))) * scale
+                            ).astype(dq_ref.dtype)
 
         # p is exactly 0 on masked slots (exp underflows at -1e30), but the
-        # explicit zeroing keeps clamped padding rows provably inert.
-        dk = dk * valid                                          # (K, H*D)
-        dv = dv * valid
+        # explicit zeroing keeps padding rows provably inert.
+        dkv_rows[:, :hdp] = ds * q_rows * valid                  # ds ⊗ q
+        dkv_rows[:, hdp:] = _spread(probs, masks) * g_rows * valid  # p ⊗ g
+        dkv = dkv_rows[...]
 
         if has_time:
-            dwtk_ref[...] += _mm(phi, dk, ((0,), (0,)))
-            dwtv_ref[...] += _mm(phi, dv, ((0,), (0,)))
-            dphi = (_mm(dk, t["wtk"][...], ((1,), (1,)))
-                    + _mm(dv, t["wtv"][...], ((1,), (1,))))
-            dtheta = -jnp.sin(theta) * dphi                      # (K, d_time)
+            dwt_ref[...] += _mm(phi, dkv, ((0,), (0,)))
+            dphi = _mm(dkv, t["wt"][...], ((1,), (1,)))          # (B*K, dt)
+            dtheta = -jnp.sin(theta) * dphi
             dtw_ref[...] += jnp.sum(dtheta * dt, axis=0, keepdims=True)
             dtb_ref[...] += jnp.sum(dtheta, axis=0, keepdims=True)
         if has_edge:
-            dwek_ref[...] += _mm(e, dk, ((0,), (0,)))  # e already eid-zeroed
-            dwev_ref[...] += _mm(e, dv, ((0,), (0,)))
+            dwe_ref[...] += _mm(e, dkv, ((0,), (0,)))  # e already eid-zeroed
 
-        # Scatter this seed's dk/dv rows into the table gradients: one
-        # sequential read-modify-write per slot (no TPU atomics; duplicate
-        # ids within a row accumulate correctly because each RMW completes
-        # before the next starts).
-        dk_rows[...] = dk
-        dv_rows[...] = dv
-
-        def rmw(kk, c):
-            nid = idx_ref[j, kk]
-            in_k = pltpu.make_async_copy(dk_hbm.at[nid], rk_row, sem_rk)
-            in_v = pltpu.make_async_copy(dv_hbm.at[nid], rv_row, sem_rv)
-            in_k.start()
-            in_v.start()
-            in_k.wait()
-            in_v.wait()
-            rk_row[...] = rk_row[...] + dk_rows[pl.ds(kk, 1), :]
-            rv_row[...] = rv_row[...] + dv_rows[pl.ds(kk, 1), :]
-            out_k = pltpu.make_async_copy(rk_row, dk_hbm.at[nid], sem_rk)
-            out_v = pltpu.make_async_copy(rv_row, dv_hbm.at[nid], sem_rv)
-            out_k.start()
-            out_v.start()
-            out_k.wait()
-            out_v.wait()
-            return c
-
-        jax.lax.fori_loop(0, kbuf, rmw, 0)
+        _scatter_rows(i, idx_ref, dkv_rows, dkv_hbm, cell, sem, bs=block_s,
+                      kbuf=kbuf)
         return carry
 
-    jax.lax.fori_loop(0, block_s, per_seed, 0)
+    jax.lax.fori_loop(0, nblk, per_block, 0)
 
 
 def fused_temporal_layer_bwd_kernel(
     g, q, k_table, v_table, seeds, seed_times, buf, *,
     time_w=None, time_b=None, wt_k=None, wt_v=None,
     edge_feats=None, we_k=None, we_v=None,
-    block_s: int = 128, scale: float | None = None,
+    block_s: int | None = None, scale: float | None = None,
     interpret: bool = False,
 ):
     """Backward pass of ``fused_temporal_layer_kernel``, gather-free in HBM.
@@ -624,87 +716,81 @@ def fused_temporal_layer_bwd_kernel(
     non-differentiable.
 
     The grid is declared sequential ("arbitrary") so the per-row DMA
-    read-modify-write scatter into dk_table/dv_table is race-free.
+    read-modify-write scatter into the table gradient is race-free.
     """
     has_time = wt_k is not None
     has_edge = we_k is not None
     lead, rest, m = _layer_inputs(q, k_table, v_table, seeds, seed_times,
                                   buf, time_w, time_b, wt_k, wt_v,
-                                  edge_feats, we_k, we_v, block_s)
-    S, H, D, HDp, bs = m["S"], m["H"], m["D"], m["HDp"], m["block_s"]
-    HD, K, N = H * D, m["K"], k_table.shape[0]
-    blocked = pl.BlockSpec((bs, HDp), lambda i: (i, 0))
+                                  edge_feats, we_k, we_v, block_s,
+                                  "kernel/attention_backward")
+    S, H, D, HDp = m["S"], m["H"], m["D"], m["HDp"]
+    HD, N = H * D, k_table.shape[0]
+    blocked = pl.BlockSpec((m["tile"], HDp), lambda i: (i, 0))
     g2 = _pad2(g.reshape(S, HD), S + m["pad"], HDp)
-    # Zero operands aliased to the table-gradient outputs: the kernel
-    # accumulates into them by DMA read-modify-write.
-    zeros = jnp.zeros((N, 1, HDp), jnp.float32)
+    # A zero operand aliased to the k | v table gradient: the kernel
+    # accumulates into it by DMA read-modify-write.
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    operands, in_specs = zip(*(lead + [(g2, blocked)] + rest
-                               + [(zeros, hbm), (zeros, hbm)]))
-    alias_base = len(operands) - 2
+    operands, in_specs = zip(*(lead + [(g2, blocked)] + rest + [
+        (jnp.zeros((N, 1, 2 * HDp), jnp.float32), hbm)]))
 
-    names = ["q", "k_table", "v_table"]
+    names = ["q", "kv"]
     out_shape = [
         jax.ShapeDtypeStruct((S + m["pad"], HDp), jnp.float32),
-        jax.ShapeDtypeStruct((N, 1, HDp), jnp.float32),
-        jax.ShapeDtypeStruct((N, 1, HDp), jnp.float32),
+        jax.ShapeDtypeStruct((N, 1, 2 * HDp), jnp.float32),
     ]
-    out_specs = [blocked, hbm, hbm]
+    out_specs = [blocked, hbm]
     resident = lambda shp: pl.BlockSpec(shp, lambda i: (0, 0))  # noqa: E731
+    groups = []
     if has_time:
         d_time = time_w.size
-        for name, shp in (("time_w", (1, d_time)), ("time_b", (1, d_time)),
-                          ("wt_k", (d_time, HDp)), ("wt_v", (d_time, HDp))):
-            names.append(name)
-            out_shape.append(jax.ShapeDtypeStruct(shp, jnp.float32))
-            out_specs.append(resident(shp))
+        groups += [("time_w", (1, d_time)), ("time_b", (1, d_time)),
+                   ("wt", (d_time, 2 * HDp))]
     if has_edge:
-        for name in ("we_k", "we_v"):
-            names.append(name)
-            out_shape.append(jax.ShapeDtypeStruct((m["DEp"], HDp),
-                                                  jnp.float32))
-            out_specs.append(resident((m["DEp"], HDp)))
+        groups += [("we", (m["DEp"], 2 * HDp))]
+    for name, shp in groups:
+        names.append(name)
+        out_shape.append(jax.ShapeDtypeStruct(shp, jnp.float32))
+        out_specs.append(resident(shp))
 
     scratch, sems = _staging_scratch(m, has_edge)
-    scratch += [
-        pltpu.VMEM((K, HDp), jnp.float32),   # dk_rows
-        pltpu.VMEM((K, HDp), jnp.float32),   # dv_rows
-        pltpu.VMEM((1, HDp), jnp.float32),   # rk_row
-        pltpu.VMEM((1, HDp), jnp.float32),   # rv_row
-        pltpu.SemaphoreType.DMA,             # sem_rk
-        pltpu.SemaphoreType.DMA,             # sem_rv
+    scratch += sems + [
+        pltpu.VMEM((m["block_s"] * m["K"], 2 * HDp), jnp.float32),  # dkv_rows
+        pltpu.VMEM((1, 2 * HDp), jnp.float32),                      # cell
+        pltpu.SemaphoreType.DMA,                                    # sem
     ]
     outs = pl.pallas_call(
         functools.partial(
             _fused_layer_bwd_kernel, scale=1.0 / np.sqrt(D) if scale is None
-            else scale, block_s=bs, kbuf=K, heads=H, hdim=D,
-            has_time=has_time, has_edge=has_edge,
+            else scale, block_s=m["block_s"], nblk=m["nblk"], kbuf=m["K"],
+            heads=H, hdim=D, has_time=has_time, has_edge=has_edge,
         ),
         grid=(m["ns"],),
         in_specs=list(in_specs),
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=scratch + sems,
-        input_output_aliases={alias_base: 1, alias_base + 1: 2},
+        scratch_shapes=scratch,
+        input_output_aliases={len(operands) - 1: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
-    grads = dict(zip(names, outs))
-    grads["q"] = grads["q"][:S, :HD]
-    for name in ("k_table", "v_table"):
-        grads[name] = grads[name].reshape(N, HDp)[:, :HD]
-    for name in ("wt_k", "wt_v"):
-        if name in grads:
-            grads[name] = grads[name][:, :HD]
-    for name in ("we_k", "we_v"):
-        if name in grads:
-            grads[name] = grads[name][:edge_feats.shape[1], :HD]
+    raw = dict(zip(names, outs))
+    kv = raw.pop("kv").reshape(N, 2 * HDp)
+    grads = {"q": raw.pop("q")[:S, :HD], "k_table": kv[:, :HD],
+             "v_table": kv[:, HDp:HDp + HD]}
+    if has_time:
+        grads.update(time_w=raw["time_w"], time_b=raw["time_b"],
+                     wt_k=raw["wt"][:, :HD], wt_v=raw["wt"][:, HDp:HDp + HD])
+    if has_edge:
+        d_edge = edge_feats.shape[1]
+        grads.update(we_k=raw["we"][:d_edge, :HD],
+                     we_v=raw["we"][:d_edge, HDp:HDp + HD])
     return grads
 
 
 def fused_recency_attention_kernel(q, k_table, v_table, seeds, buf_ids, *,
-                                   block_s: int = 128,
+                                   block_s: int | None = None,
                                    scale: float | None = None,
                                    interpret: bool = False):
     """Fused neighbor-gather + attention over the resident recency buffer.
@@ -716,8 +802,8 @@ def fused_recency_attention_kernel(q, k_table, v_table, seeds, buf_ids, *,
     Returns (S, H, D).
 
     Thin wrapper over ``fused_temporal_layer_kernel`` with the time/edge
-    bias folds disabled (ids-only buffer): same double-buffered DMA body,
-    no (S, K, H, D) HBM intermediate.
+    bias folds disabled (ids-only buffer): same blocked, double-buffered
+    DMA body, no (S, K, H, D) HBM intermediate.
     """
     buf_ids = buf_ids.astype(jnp.int32)
     buf = jnp.stack(

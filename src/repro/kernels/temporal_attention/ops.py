@@ -66,7 +66,7 @@ def _use_kernel(mode: str) -> bool:
 
 
 @partial(jax.jit, static_argnames=("block_s", "mode"))
-def temporal_attention(q, k, v, mask, *, block_s: int = 128,
+def temporal_attention(q, k, v, mask, *, block_s: int = 32,
                        mode: str = "auto"):
     """q: (S, H, D); k, v: (S, K, H, D); mask: (S, K) -> (S, H, D)."""
     if _use_kernel(mode):
@@ -77,7 +77,7 @@ def temporal_attention(q, k, v, mask, *, block_s: int = 128,
 
 @partial(jax.jit, static_argnames=("block_s", "mode"))
 def fused_recency_attention(q, k_table, v_table, seeds, buf_ids, *,
-                            block_s: int = 128, mode: str = "auto"):
+                            block_s: int | None = None, mode: str = "auto"):
     """q: (S, H, D); k_table, v_table: (N, H, D); seeds: (S,);
     buf_ids: (Nb, K) resident buffer rows -> (S, H, D)."""
     if _use_kernel(mode):
@@ -125,7 +125,7 @@ _fused_layer_call.defvjp(_fused_layer_fwd, _fused_layer_bwd)
 def fused_temporal_layer(q, k_table, v_table, seeds, seed_times, buf, *,
                          time_w=None, time_b=None, wt_k=None, wt_v=None,
                          edge_feats=None, we_k=None, we_v=None,
-                         block_s: int = 128, mode: str = "auto"):
+                         block_s: int | None = None, mode: str = "auto"):
     """Fused TGAT/TGN-style layer attention over the packed recency buffer.
 
     Computes, for each seed ``s`` with packed buffer row ``buf[seeds[s]]``:
@@ -147,6 +147,9 @@ def fused_temporal_layer(q, k_table, v_table, seeds, seed_times, buf, *,
       * ``"kernel"``    — force the Pallas kernel (compiled);
       * ``"interpret"`` — force the kernel in interpret mode (CPU parity
                           tests and jaxpr inspection).
+
+    ``block_s`` overrides the seeds the kernels compute per block (tests);
+    by default they derive it from the shapes (``kernel._plan``).
 
     The kernel path is differentiable via a custom VJP whose backward is
     the flash-style Pallas backward kernel — both directions of a jitted
@@ -213,7 +216,7 @@ def fused_temporal_layer_sharded(q, k_table, v_table, seeds, seed_times,
                                  buf, *, axis: str, rows_per_shard: int,
                                  time_w=None, time_b=None, wt_k=None,
                                  wt_v=None, edge_feats=None, we_k=None,
-                                 we_v=None, block_s: int = 128,
+                                 we_v=None, block_s: int | None = None,
                                  mode: str = "auto"):
     """Shard-aware ``fused_temporal_layer``: partial attention per node
     shard, assembled exactly by one psum over the mesh's node axis.

@@ -354,6 +354,17 @@ class _CompileListener:
 _compiles = _CompileListener()
 
 
+def note(name: str, **attrs) -> None:
+    """Record ``name`` with ``attrs`` as a span of no duration in every
+    enabled ``Telemetry`` — for code with no pipeline at hand, such as a
+    kernel wrapper reporting, as it traces, the blocking it chose. Free
+    when none is enabled."""
+    with _compiles._lock:
+        enabled = list(_compiles._enabled)
+    for tel in enabled:
+        tel._ended(name, 0.0, attrs)
+
+
 #: Shared disabled instance — the default for instrumented call sites
 #: that never attach sinks themselves (do not attach sinks to it).
 NULL = Telemetry()
