@@ -9,7 +9,9 @@ norm of each leaf's change over the steps.
 Evaluation: the scores of given batches, under the weights the run used.
 
 Each model's equations are in ``reference/<name>.py``, found by the
-configuration's model name.
+configuration's model name. A model scores a link either from one
+embedding per seed (``embed``, then the shared decoder) or, where the
+score depends on the pair, by its own ``link_logits``.
 
 ``dtype`` float32 runs at the highest matmul precision (the reference);
 bfloat16 is its control, the next precision down.
@@ -31,7 +33,16 @@ from .recency import Recency
 
 def model(name: str):
     """The reference model ``name``: the module ``reference/<name>.py``,
-    with ``HOPS``, ``layout``, ``init_state``, ``embed`` and ``update``."""
+    with ``HOPS``, ``layout``, ``init_state``, ``update``, and ``embed``
+    or ``link_logits``.
+
+    ``embed(params, heads, g, state, dtype, block=0)`` gives one row per
+    seed ``[src | dst | negatives]``, scored by ``layers.link_logits``.
+    A model whose score depends on the pair defines instead
+    ``link_logits(params, heads, g, state, dtype, b, block=0)`` ->
+    positive (B,) and negative (B, Nn) logits, from the same ``g``
+    (``batch_inputs``: every seed's neighborhood); ``block`` > 0 bounds
+    how many pairs it computes at once."""
     return importlib.import_module(f"{__package__}.{name}")
 
 
@@ -129,9 +140,8 @@ def train(spec: dict, opt: dict, stream: Stream, batches, params,
     state = mod.init_state(kw, num_nodes, dtype)
 
     def loss_fn(p, g, ev, state):
-        b = ev["src"].shape[0]
-        h = mod.embed(p, heads, g, state, dtype)
-        pos, neg = layers.link_logits(p, h, b)
+        pos, neg = _logits(mod, p, heads, g, state, dtype,
+                           ev["src"].shape[0])
         return layers.bce(pos, neg, ev["mask"])
 
     losses, first = [], None
@@ -151,9 +161,26 @@ def train(spec: dict, opt: dict, stream: Stream, batches, params,
     return {"losses": losses, "grad": first, "change": leaf_norms(change)}
 
 
+def _logits(mod, params, heads: int, g, state, dtype, b: int,
+            block: int = 0, jitted: bool = False):
+    """Positive (B,) and negative (B, Nn) logits of the batch in ``g``:
+    the model's own ``link_logits`` where it has one, else its ``embed``
+    and the shared decoder. ``jitted``: through the model's functions
+    compiled apart (eval); else traced in place (inside train's step)."""
+    if hasattr(mod, "link_logits"):
+        fn = _jitted(mod, "link_logits") if jitted else mod.link_logits
+        return fn(params, heads=heads, g=g, state=state, dtype=dtype, b=b,
+                  block=block)
+    fn = _jitted(mod, "embed") if jitted else mod.embed
+    h = fn(params, heads=heads, g=g, state=state, dtype=dtype, block=block)
+    return layers.link_logits(params, h, b)
+
+
 @functools.cache
-def _jitted_embed(mod):
-    return jax.jit(mod.embed, static_argnames=("heads", "dtype", "block"))
+def _jitted(mod, name: str):
+    static = ("heads", "dtype", "block") + (("b",) if name == "link_logits"
+                                            else ())
+    return jax.jit(getattr(mod, name), static_argnames=static)
 
 
 def eval_scores(spec: dict, stream: Stream, batch: dict, params,
@@ -169,9 +196,8 @@ def eval_scores(spec: dict, stream: Stream, batch: dict, params,
     params = jax.tree.map(lambda x: jnp.asarray(x, dtype), params)
     g, _ = batch_inputs(stream, batch, mod.HOPS, dtype)
     with _precision(dtype):
-        h = _jitted_embed(mod)(params, heads=heads, g=g, state=None,
-                               dtype=dtype, block=block)
-        pos, neg = layers.link_logits(params, h, batch["src"].shape[0])
+        pos, neg = _logits(mod, params, heads, g, None, dtype,
+                           batch["src"].shape[0], block, jitted=True)
     return np.asarray(pos, np.float64), np.asarray(neg, np.float64)
 
 

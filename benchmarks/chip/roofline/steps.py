@@ -2,7 +2,8 @@
 batch's own ids. What differs by model is in ``roofline/<name>.py``.
 
 ``step_work`` returns the fused attention calls of the step (forward,
-and backward when training), each as ``attention.Work``, and the step's
+and backward when training), each as ``attention.Work``, none for a model
+that runs no fused kernel (``FUSED = False`` in its module), and the step's
 model operations: every product and sum the equations need for the valid
 events of the batch, forward and backward for training (backward counted
 as twice the forward), the memory update (forward only: no gradient flows
@@ -26,7 +27,8 @@ ADAMW_OPS = 12  # per parameter: two moments, two corrections, sqrt, step
 
 def model(name: str):
     """The work counts of model ``name``: ``roofline/<name>.py``, with
-    ``KEYS``, ``num_params`` and ``forward``."""
+    ``KEYS``, ``num_params``, ``forward`` and, optionally, ``FUSED``
+    (default True: the batch's first hop is a fused attention call)."""
     return importlib.import_module(f"{__package__}.{name}")
 
 
@@ -60,8 +62,6 @@ def step_work(name: str, kw: dict, k: int, d_edge: int, num_nodes: int,
               arrays: dict, train: bool):
     """``(kernel calls, model operations)`` of one step whose batch held
     ``arrays`` (host copies of ``model(name).KEYS``)."""
-    d, d_t, heads = kw["d_model"], kw["d_time"], kw["num_heads"]
-    dm = att.Dims(d=d, heads=heads, d_time=d_t, d_edge=d_edge, k=k)
     seeds = np.asarray(arrays["seed_nodes"])
     mask = np.asarray(arrays["batch_mask"], bool)
     n_ev = mask.size
@@ -77,10 +77,15 @@ def step_work(name: str, kw: dict, k: int, d_edge: int, num_nodes: int,
         pairs=float(mask.sum()) * (1 + n_neg))
     mod = model(name)
     more, fwd, update = mod.forward(kw, d_edge, b, arrays)
-    calls = [first] + more
-    kernel = [att.forward(dm, c) for c in calls]
+    calls = [first] + more if getattr(mod, "FUSED", True) else []
+    kernel = []
+    if calls:
+        dm = att.Dims(d=kw["d_model"], heads=kw["num_heads"],
+                      d_time=kw["d_time"], d_edge=d_edge, k=k)
+        kernel = [att.forward(dm, c) for c in calls]
+        if train:
+            kernel += [att.backward(dm, c) for c in calls]
     if not train:
         return kernel, fwd + update
-    kernel += [att.backward(dm, c) for c in calls]
     params = mod.num_params(kw, num_nodes, d_edge)
     return kernel, 3 * fwd + update + ADAMW_OPS * params

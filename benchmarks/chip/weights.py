@@ -54,8 +54,12 @@ def layout(model: dict, num_nodes: int, d_edge: int) -> dict:
 def _init(path: str, shape, key):
     """Node embeddings N(0, 0.02); time frequencies and phases N(0, 0.1)
     (TGAT's Bochner encoding); matrices N(0, 2 / (fan_in + fan_out));
-    biases N(0, 0.02)."""
+    a norm's gain (a leaf named ``scale``) 1 + N(0, 0.02), near the ones
+    a model starts from, so every branch behind a norm keeps its size;
+    other vectors (biases) N(0, 0.02)."""
     x = jax.random.normal(key, shape, jnp.float32)
+    if path.rsplit(".", 1)[-1] == "scale":
+        return 1.0 + x * 0.02
     if path.endswith("emb"):
         return x * 0.02
     if path.startswith("time"):
